@@ -61,7 +61,7 @@ INT8_LANES = [
 ]
 
 
-def _int8_lane_cfg() -> LaneConfig:
+def int8_lane_config() -> LaneConfig:
     return LaneConfig(int8_r_max=3, int8_p_zero=0.33, int8_b_zo=1,
                       int8_b_bp=5)
 
@@ -107,7 +107,7 @@ def lenet_int8_lanes(steps=600, batch=64, train_n=2048, test_n=512, seed=0,
     qx_te = quant_from_float(jnp.asarray(xs_te))
     results = {}
     for name, c, tail in INT8_LANES:
-        lane = _int8_lane_cfg()
+        lane = int8_lane_config()
         step = jax.jit(make_int8_elastic_step(
             lenet.lenet5_forward_int8,
             partition_fn=lambda p, c=c: lenet.partition_at(p, c),
@@ -253,7 +253,8 @@ def pointnet_memory_table(batch: int, num_points=1024):
 # measured memory: XLA buffer assignment per lane, next to Eqs. 2-4/13-15
 # ------------------------------------------------------------------ #
 def lenet_measured_memory(batch: int = 32) -> Dict[str, Dict[str, int]]:
-    """MEASURED per-lane step footprint for the four fp32 paper lanes.
+    """MEASURED per-lane step footprint for the four fp32 paper lanes, at
+    one probe per step (Alg. 1, and what Eqs. 2-4 count).
 
     Lowers and compiles (never runs) the exact production train step —
     same ``make_elastic_step`` program, same state donation as the train
@@ -267,7 +268,7 @@ def lenet_measured_memory(batch: int = 32) -> Dict[str, Dict[str, int]]:
     xs, ys = glyphs(batch, seed=0)
     batch_d = {"x": jnp.asarray(xs), "y": jnp.asarray(ys)}
     rows = {}
-    for name, lane, c in lenet_lane_configs():
+    for name, lane, c in lenet_lane_configs(probes=1):
         params = lenet.init_lenet5(jax.random.key(7))
         part = (lambda p, c=c: lenet.partition_at(p, c)) \
             if lane.lane == "elastic_zo" else None
@@ -298,7 +299,7 @@ def lenet_int8_measured_memory(batch: int = 32) -> Dict[str, Dict[str, int]]:
         step = make_int8_elastic_step(
             lenet.lenet5_forward_int8,
             partition_fn=lambda p, c=c: lenet.partition_at(p, c),
-            tail_fcs=tail, lane=_int8_lane_cfg(), loss_mode="int")
+            tail_fcs=tail, lane=int8_lane_config(), loss_mode="int")
         params = lenet.init_lenet5_int8(jax.random.key(7))
         state = TrainState(params, jnp.int32(0),
                            jax.random.key_data(jax.random.key(13)))

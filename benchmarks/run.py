@@ -12,7 +12,9 @@ from __future__ import annotations
 
 import argparse
 import json
+import sys
 import time
+import traceback
 from pathlib import Path
 
 from .bench_util import write_bench
@@ -241,6 +243,7 @@ def main() -> None:
     if not obs.get().enabled:
         obs.install()      # BENCH_paper.json always carries timings
     ran = []
+    failed = []
     metrics = {}
     rec = obs.get()
     for name, fn in SECTIONS.items():
@@ -251,10 +254,14 @@ def main() -> None:
             with rec.span(f"bench/{name}", track="main"):
                 metrics.update(fn(args.fast))
             ran.append(name)
-        except Exception as e:  # noqa: BLE001
-            print(f"# [{name}] ERROR {type(e).__name__}: {e}")
-            metrics[f"{name}_error"] = f"{type(e).__name__}:{e}"
+        except Exception:  # noqa: BLE001 — report, run the rest, exit 1
+            traceback.print_exc()
+            print(f"# [{name}] ERROR")
+            failed.append(name)
         print(f"# [{name}] done in {time.perf_counter()-t0:.1f}s")
+    if failed:
+        sys.exit(f"# sections failed: {', '.join(failed)}; "
+                 "BENCH_paper.json not written")
     obs.memory.sample()        # final tagged-vs-jax reconciliation
     write_bench("paper", {"fast": args.fast, "sections": ",".join(ran)},
                 metrics, out=args.out or None,

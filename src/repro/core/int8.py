@@ -40,12 +40,23 @@ def dequant(q: QTensor) -> jax.Array:
     return q.data.astype(jnp.float32) * jnp.exp2(q.exp.astype(jnp.float32))
 
 
+def _pow2(n: jax.Array) -> jax.Array:
+    """2.0**n as float32 for integer n in [-126, 127], built from its
+    bits — exact on every backend, unlike ``exp2``."""
+    n = jnp.asarray(n, jnp.int32)
+    return jax.lax.bitcast_convert_type((n + 127) << 23, jnp.float32)
+
+
 def quant_from_float(x, bits=7):
-    """Quantize fp32 -> QTensor with max-|x| scaling (init / input path)."""
-    m = jnp.max(jnp.abs(x))
-    m = jnp.maximum(m, 1e-30)
-    exp = jnp.ceil(jnp.log2(m)) - bits
-    data = jnp.clip(jnp.round(x / jnp.exp2(exp)), -127, 127).astype(jnp.int8)
+    """Quantize fp32 -> QTensor with max-|x| scaling (init / input path).
+
+    Only exact float ops: the exponent ceil(log2 max|x|) comes from
+    frexp, and scaling by a power of two and round-half-even are exact,
+    so every backend produces the same bits."""
+    m = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30)
+    frac, e = jnp.frexp(m)                   # m = frac * 2^e, frac in [.5, 1)
+    exp = e - (frac == 0.5).astype(jnp.int32) - bits
+    data = jnp.clip(jnp.round(x * _pow2(-exp)), -127, 127).astype(jnp.int8)
     return QTensor(data, exp.astype(jnp.int32))
 
 

@@ -119,7 +119,9 @@ NEG_INF = -1e30
 def _monotone_key(x: jax.Array) -> jax.Array:
     """float32 -> uint32 order-preserving key (-0.0 canonicalized to +0.0,
     so key comparisons agree with float comparisons everywhere)."""
-    x = x.astype(jnp.float32) + jnp.float32(0.0)
+    x = x.astype(jnp.float32)
+    # a select, not x + 0.0, which the compiler may fold away under jit
+    x = jnp.where(x == 0.0, jnp.float32(0.0), x)
     s = jax.lax.bitcast_convert_type(x, jnp.int32)
     u = s.astype(jnp.uint32)
     return jnp.where(s < 0, ~u, u | jnp.uint32(0x80000000))

@@ -38,12 +38,24 @@ def _hash_block(row0, shape, seed, salt):
     return prng._fmix32(h + seed.astype(jnp.uint32) * prng._M2)
 
 
+def _u32_to_f32(x):
+    """uint32 -> float32 rounded to nearest even, as XLA's convert does.
+
+    The high half is exact in float32 and the low half is below 2^16,
+    so the one rounding happens in the add, on the exact sum."""
+    hi = (x >> np.uint32(16)).astype(jnp.int32).astype(jnp.float32)
+    lo = (x & np.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return hi * np.float32(65536.0) + lo
+
+
 def _normal_block(row0, shape, seed, salt):
     b1 = _hash_block(row0, shape, seed, 2 * salt + np.uint32(1))
     b2 = _hash_block(row0, shape, seed, 2 * salt + np.uint32(2))
-    u1 = (b1 >> np.uint32(8)).astype(jnp.float32) * np.float32(2 ** -24) \
+    # 24-bit values convert exactly through int32; Mosaic has no direct
+    # uint32 -> float32 cast
+    u1 = _u32_to_f32(b1 >> np.uint32(8)) * np.float32(2 ** -24) \
         + np.float32(2 ** -25)
-    u2 = (b2 >> np.uint32(8)).astype(jnp.float32) * np.float32(2 ** -24)
+    u2 = _u32_to_f32(b2 >> np.uint32(8)) * np.float32(2 ** -24)
     r = jnp.sqrt(-2.0 * jnp.log(u1))
     return r * jnp.cos(np.float32(2.0 * np.pi) * u2)
 
@@ -55,7 +67,7 @@ def _int8_noise_block(row0, shape, seed, salt, r_max, p_zero):
     bits_m = _hash_block(row0, shape, seed, 3 * salt + np.uint32(2))
     u = (bits_u % (2 * r_max + 1).astype(jnp.uint32)).astype(jnp.int32) \
         - r_max.astype(jnp.int32)
-    keep = (bits_m.astype(jnp.float32)
+    keep = (_u32_to_f32(bits_m)
             < (1.0 - p_zero) * np.float32(2 ** 32)).astype(jnp.int32)
     return u * keep
 

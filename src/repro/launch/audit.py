@@ -14,6 +14,7 @@ import argparse
 from ..configs import LaneConfig, get_arch, get_shape
 from .dryrun import lower_cell
 from .hlo_analysis import collective_bytes
+from .cache import enable_compile_cache
 from .mesh import make_production_mesh
 
 
@@ -49,6 +50,7 @@ def main(argv=None):
     ap.add_argument("--top", type=int, default=15)
     ap.add_argument("--multi", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     audit(args.arch, args.shape, args.strategy, args.top, args.multi,
           args.lane)
 

@@ -33,6 +33,7 @@ from ..core.elastic import TrainState
 from ..sharding.params import cache_shardings, param_shardings
 from ..sharding.rules import ShardingRules
 from .hlo_analysis import collective_bytes, summarize
+from .cache import enable_compile_cache
 from .mesh import make_production_mesh
 
 RESULTS = Path(__file__).resolve().parents[3] / "results" / "dryrun"
@@ -216,6 +217,7 @@ def main(argv=None):
                     help="recompute only depth variants of existing cells")
     ap.add_argument("--out", default=str(RESULTS))
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     lane = LaneConfig(lane=args.lane, fused_probes=args.fused)
     out_dir = Path(args.out)

@@ -51,6 +51,7 @@ from ..fleet import (make_int8_probe_fn, make_reference_step,
                      parse_byzantine, reference_state, run_fleet)
 from ..sharding.rules import ShardingRules
 from ..train.train_loop import LoopConfig, run
+from .cache import enable_compile_cache
 
 
 def _parse_partitions(ap, args):
@@ -189,6 +190,7 @@ def main(argv=None):
     obs.add_observability_args(ap)
     args = ap.parse_args(argv)
     obs.configure_from_args(args)
+    enable_compile_cache()
 
     crashes = _parse_crashes(ap, args)
     try:

@@ -16,6 +16,7 @@ import numpy as np
 from .. import obs
 from ..configs import LaneConfig, ServeConfig, get_arch, reduced
 from ..serve import Engine, SamplingParams, dense_generate
+from .cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -40,6 +41,7 @@ def main(argv=None):
     obs.add_observability_args(ap)
     args = ap.parse_args(argv)
     obs.configure_from_args(args)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:

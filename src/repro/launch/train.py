@@ -2,12 +2,13 @@
 
 Runs real steps on the available devices (CPU smoke scale by default, the
 full production mesh when launched on a TPU slice). For the compile-only
-512-way proof use ``repro.launch.dryrun``.
+512-way proof use ``repro.launch.dryrun``. ``main`` returns the run's
+``RunResult`` (final state, logged losses).
 """
 from __future__ import annotations
 
 import argparse
-
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,8 @@ from ..core import api
 from ..data.synthetic import token_batch
 from ..sharding.params import param_shardings
 from ..sharding.rules import ShardingRules
-from ..train.train_loop import LoopConfig, init_state, run
+from ..train.train_loop import LoopConfig, RunResult, init_state, run
+from .cache import enable_compile_cache
 from .mesh import make_mesh
 
 
@@ -32,6 +34,9 @@ def main(argv=None):
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--smoke", action="store_true",
                     help="reduced same-family config (CPU-trainable)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to this many layers, every width "
+                         "kept (0 = the published depth)")
     ap.add_argument("--bp-tail-layers", type=int, default=1)
     ap.add_argument("--probes", type=int, default=1)
     ap.add_argument("--probe-drop", type=float, default=0.0)
@@ -47,10 +52,13 @@ def main(argv=None):
     obs.add_observability_args(ap)
     args = ap.parse_args(argv)
     obs.configure_from_args(args)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = reduced(cfg)
+    if args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
     shape = ShapeConfig("cli", seq_len=args.seq, global_batch=args.batch,
                         kind="train")
     lane = LaneConfig(lane=args.lane, bp_tail_layers=args.bp_tail_layers,
@@ -103,6 +111,7 @@ def main(argv=None):
     obs.log("train", f"done at step {int(state.step)}; "
             f"logged {len(history)} loss points")
     obs.write_outputs(args)
+    return RunResult(state, history)
 
 
 if __name__ == "__main__":

@@ -5,6 +5,7 @@ the paper's ZO/BP split accounting (ZO-Feat-Cls1 trains 106,936, Cls2
 """
 from __future__ import annotations
 
+import math
 from typing import Dict
 
 import jax
@@ -85,8 +86,23 @@ def partition_at(params: Dict, c: int):
 # INT8 (NITI) variant — no biases, QTensor weights
 # ------------------------------------------------------------------ #
 def init_lenet5_int8(key, cfg: LeNet5Config = LeNet5Config()):
-    fp = init_lenet5(key, cfg)
-    return {n: {"w": quant_from_float(fp[n]["w"], bits=6)} for n in LAYER_NAMES}
+    """6-bit quantized N(0, 1/fan_in) weights, built without an inexact
+    float op so that every backend gives the same bits: each normal draw
+    is the sum of 12 uniform 16-bit integers of the threefry stream
+    (Irwin-Hall), and the quantizer is exact. (``jax.random.normal``'s
+    erf_inv differs in the last bit between the CPU and the TPU, which
+    moves weights across rounding boundaries.)"""
+    shapes = jax.eval_shape(lambda: init_lenet5(key, cfg))
+    out = {}
+    for name, k in zip(LAYER_NAMES, jax.random.split(key, len(LAYER_NAMES))):
+        shape = shapes[name]["w"].shape
+        u = jax.random.bits(k, (12,) + shape, jnp.uint32) >> 16
+        s = jnp.sum(u.astype(jnp.int32), axis=0) - 6 * 65535   # mean 0, sd ~2^16
+        fan_in = math.prod(shape[:-1])
+        w = s.astype(jnp.float32) * jnp.float32(
+            1.0 / (65536.0 * math.sqrt(fan_in)))
+        out[name] = {"w": quant_from_float(w, bits=6)}
+    return out
 
 
 def lenet5_forward_int8(params, x: QTensor):

@@ -62,18 +62,25 @@ def tree_nbytes(tree) -> int:
 
 
 def device_memory_stats() -> Optional[Dict[str, int]]:
-    """Byte-valued ``memory_stats()`` of device 0, or None.
+    """Byte-valued ``memory_stats()`` of device 0, or None off the TPU.
 
     The CPU backend has no allocator stats (returns None) — callers
-    must treat this as best-effort; ``jax.live_arrays()`` is the
-    portable source of truth.
+    must treat this as best-effort there; ``jax.live_arrays()`` is the
+    portable source of truth. A TPU always reports them, so there a
+    failed or empty reading raises instead of hiding a missing peak.
     """
     import jax
+    dev = jax.devices()[0]
+    on_tpu = dev.platform == "tpu"
     try:
-        st = jax.devices()[0].memory_stats()
+        st = dev.memory_stats()
     except Exception:
+        if on_tpu:
+            raise
         return None
     if not st:
+        if on_tpu:
+            raise RuntimeError(f"{dev.device_kind} reported no memory_stats()")
         return None
     return {k: int(v) for k, v in st.items()
             if "bytes" in k and isinstance(v, (int, float))}

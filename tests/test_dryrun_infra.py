@@ -29,10 +29,8 @@ def test_collective_parser_counts_scan_trips():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_analysis import collective_bytes, summarize
-        kw = {}
-        if hasattr(jax.sharding, "AxisType"):      # jax >= 0.5 only
-            kw["axis_types"] = (jax.sharding.AxisType.Auto,) * 2
-        mesh = jax.make_mesh((2, 4), ("data", "model"), **kw)
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         def step(ws, x):
             def body(c, w):
                 # row-sharded matmul -> all-reduce inside the scan body

@@ -1,8 +1,9 @@
 """Update-engine refactor contract: golden digests (tests/golden_cases.py).
 
 ``preserved``: the engine-built fp32 elastic_zo/full_zo/full_bp steps
-must reproduce the *pre-refactor* implementation bit for bit (digests
-captured before core/engine.py existed). ``canonical``: multi-probe
+must reproduce the pinned single-probe digests bit for bit (re-pinned
+on jax 0.9.0, whose jax.random streams differ from jax<0.5; see
+golden_cases.py). ``canonical``: multi-probe
 fp32 (accumulate-then-cast probe fold) and the int8 lane (per-probe key
 schedule + accumulate-then-clamp) pin the engine's canonical semantics
 against future refactors.

@@ -233,12 +233,23 @@ def test_compiled_footprint_donation_shrinks_or_matches():
 
 def test_step_memory_analysis_orders_lanes_like_the_paper():
     """The measured twin of the paper's Table: full-BP's XLA peak must
-    exceed full-ZO's on the same LeNet step (the headline claim)."""
+    exceed every ZO lane's on the same LeNet step (the headline claim),
+    and the BP tail must cost the elastic lanes only a few percent.
+
+    Measured at one probe per step, Alg. 1's setting. On the CPU
+    backend of jax 0.9.0 a 4-probe step holds every probe's forward
+    buffers at once: full ZO measures 10,662,396 B there against full
+    BP's 8,037,140 B. At one probe, full ZO measures 3,177,584 B,
+    ZO-Feat-Cls2 3,049,776 B and ZO-Feat-Cls1 3,167,728 B: the tail's
+    cost (the paper's 0.072-1.7%) is below XLA's scheduling noise, so
+    the elastic lanes are held to within 10% of full ZO, not above it."""
     from benchmarks.paper_tables import lenet_measured_memory
 
     lanes = lenet_measured_memory(batch=32)
     if not lanes:
         pytest.skip("memory_analysis unavailable on this backend")
-    assert lanes["full_bp"]["peak_bytes"] > lanes["full_zo"]["peak_bytes"]
-    assert lanes["zo_feat_cls2"]["peak_bytes"] >= \
-        lanes["full_zo"]["peak_bytes"]
+    zo = lanes["full_zo"]["peak_bytes"]
+    for name in ("full_zo", "zo_feat_cls1", "zo_feat_cls2"):
+        assert lanes["full_bp"]["peak_bytes"] > 2 * lanes[name]["peak_bytes"]
+    for name in ("zo_feat_cls1", "zo_feat_cls2"):
+        assert abs(lanes[name]["peak_bytes"] - zo) <= 0.1 * zo, name

@@ -273,7 +273,9 @@ def test_serve_cli_paged_smoke():
          "--smoke", "--paged", "--batch", "2", "--prompt-len", "8",
          "--tokens", "4", "--page-size", "4"],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": src, "PATH": "/usr/bin:/bin"})
+        env={"PYTHONPATH": src, "PATH": "/usr/bin:/bin",
+             # the CLI's main() turns the persistent cache on; tests don't
+             "JAX_ENABLE_COMPILATION_CACHE": "false"})
     assert r.returncode == 0, r.stderr[-2000:]
     assert "[serve] paged:" in r.stdout
     assert "pages: peak" in r.stdout

@@ -17,7 +17,7 @@ from ..models import transformer as tf
 from ..models.transformer import (embed, head_logits, lm_loss, make_caches,
                                   run_encoder, run_periods)
 from ..sharding.rules import ShardingRules
-from . import elastic
+from . import elastic, zo as zo_mod
 from .elastic import TrainState
 
 
@@ -94,18 +94,22 @@ def build(cfg: ModelConfig, shape: ShapeConfig, lane: LaneConfig,
         return params
 
     # ---------------- forward ------------------------------------------ #
-    def backbone(params, tokens, positions, mode, *, img_embeds=None,
-                 frames=None, caches=None, cache_len=None, paged=None,
-                 full_kv=False):
+    # The stack runs in two halves: the ZO head (embedding and
+    # ``periods_zo``) and the BP tail (``periods_bp``). The training loss
+    # names each half's device time (``zo_forward``, ``bp_tail``; see
+    # core/zo.py); prefill and decode run the same halves unnamed.
+    def zo_half(params, tokens, positions, mode, *, img_embeds=None,
+                frames=None, caches=None, cache_len=None, paged=None,
+                full_kv=False):
+        """Embedding and the ZO periods -> (x, their caches, enc_out)."""
         enc_out = None
         if cfg.encoder_layers and mode != "decode":
             enc_out = run_encoder(params, frames, cfg, rules,
                                   unroll=scan_unroll)
         x = embed(params, tokens, cfg, rules, positions, img_embeds)
-        cz = caches["zo"] if caches is not None else None
-        cb = caches["bp"] if caches is not None else None
         x, ncz = run_periods(params["periods_zo"], x, cfg, rules,
-                             positions=positions, mode=mode, caches=cz,
+                             positions=positions, mode=mode,
+                             caches=None if caches is None else caches["zo"],
                              cache_len=cache_len, enc_out=enc_out,
                              remat=remat, unroll=scan_unroll, paged=paged,
                              full_kv=full_kv)
@@ -113,11 +117,28 @@ def build(cfg: ModelConfig, shape: ShapeConfig, lane: LaneConfig,
             x = jax.lax.stop_gradient(x)
             if enc_out is not None:
                 enc_out = jax.lax.stop_gradient(enc_out)
-        x, ncb = run_periods(params["periods_bp"], x, cfg, rules,
-                             positions=positions, mode=mode, caches=cb,
-                             cache_len=cache_len, enc_out=enc_out,
-                             remat=remat, unroll=scan_unroll, paged=paged,
-                             full_kv=full_kv)
+        return x, ncz, enc_out
+
+    def tail_half(params, x, positions, mode, *, enc_out=None, caches=None,
+                  cache_len=None, paged=None, full_kv=False):
+        """The BP-tail periods -> (x, their caches)."""
+        return run_periods(params["periods_bp"], x, cfg, rules,
+                           positions=positions, mode=mode,
+                           caches=None if caches is None else caches["bp"],
+                           cache_len=cache_len, enc_out=enc_out,
+                           remat=remat, unroll=scan_unroll, paged=paged,
+                           full_kv=full_kv)
+
+    def backbone(params, tokens, positions, mode, *, img_embeds=None,
+                 frames=None, caches=None, cache_len=None, paged=None,
+                 full_kv=False):
+        x, ncz, enc_out = zo_half(params, tokens, positions, mode,
+                                  img_embeds=img_embeds, frames=frames,
+                                  caches=caches, cache_len=cache_len,
+                                  paged=paged, full_kv=full_kv)
+        x, ncb = tail_half(params, x, positions, mode, enc_out=enc_out,
+                           caches=caches, cache_len=cache_len, paged=paged,
+                           full_kv=full_kv)
         new_caches = ({"zo": ncz, "bp": ncb}
                       if mode in ("decode", "prefill") else None)
         return x, new_caches
@@ -129,16 +150,21 @@ def build(cfg: ModelConfig, shape: ShapeConfig, lane: LaneConfig,
         S_tot = S_tok + n_img
         positions = jnp.broadcast_to(
             jnp.arange(S_tot, dtype=jnp.int32), (B, S_tot))
-        x, _ = backbone(params, tokens, positions, "train",
-                        img_embeds=batch.get("img"), frames=batch.get("frames"))
-        if n_img:
-            x = x[:, n_img:]
-        return lm_loss(params, x, batch["labels"], batch["mask"], cfg, rules)
+        with jax.named_scope(zo_mod.FORWARD):
+            x, _, enc_out = zo_half(params, tokens, positions, "train",
+                                    img_embeds=batch.get("img"),
+                                    frames=batch.get("frames"))
+        with jax.named_scope(zo_mod.TAIL):
+            x, _ = tail_half(params, x, positions, "train", enc_out=enc_out)
+            if n_img:
+                x = x[:, n_img:]
+            return lm_loss(params, x, batch["labels"], batch["mask"], cfg,
+                           rules)
 
     paired_loss_fn = None
     if lane.fused_probes and lane.lane == "elastic_zo":
         from ..models.transformer import run_periods_paired
-        from . import prng, zo as zo_mod
+        from . import prng
 
         def paired_loss(bp_part, zo_part, batch, key):
             tokens = batch["tokens"]
@@ -150,38 +176,42 @@ def build(cfg: ModelConfig, shape: ShapeConfig, lane: LaneConfig,
             rest = {k: v for k, v in zo_part.items() if k != "periods_zo"}
             rest_p = zo_mod.perturb(rest, key, lane.zo_eps)
             rest_m = zo_mod.perturb(rest, key, -lane.zo_eps)
-            enc_pair = (None, None)
-            if cfg.encoder_layers:      # whisper: encoder stays unfused
-                enc_pair = (run_encoder(rest_p, batch["frames"], cfg, rules,
-                                        unroll=scan_unroll),
-                            run_encoder(rest_m, batch["frames"], cfg, rules,
-                                        unroll=scan_unroll))
-            xp = embed(rest_p, tokens, cfg, rules, positions,
-                       batch.get("img"))
-            xm = embed(rest_m, tokens, cfg, rules, positions,
-                       batch.get("img"))
-            periods = zo_part["periods_zo"]
-            n_per = jax.tree.leaves(periods)[0].shape[0]
-            salts = jax.tree_util.tree_map_with_path(
-                lambda p, _: zo_mod.path_salt(p, "['periods_zo']"), periods)
-            sizes = jax.tree.map(lambda a: a.size // n_per, periods)
-            xp, xm = run_periods_paired(
-                periods, (xp, xm), cfg, rules, positions=positions,
-                seed=seed, eps=lane.zo_eps, salts=salts, sizes=sizes,
-                remat=remat, unroll=scan_unroll, enc_pair=enc_pair)
-            xp = jax.lax.stop_gradient(xp)
-            xm = jax.lax.stop_gradient(xm)
+            with jax.named_scope(zo_mod.FORWARD):
+                enc_pair = (None, None)
+                if cfg.encoder_layers:      # whisper: encoder stays unfused
+                    enc_pair = (run_encoder(rest_p, batch["frames"], cfg,
+                                            rules, unroll=scan_unroll),
+                                run_encoder(rest_m, batch["frames"], cfg,
+                                            rules, unroll=scan_unroll))
+                xp = embed(rest_p, tokens, cfg, rules, positions,
+                           batch.get("img"))
+                xm = embed(rest_m, tokens, cfg, rules, positions,
+                           batch.get("img"))
+                periods = zo_part["periods_zo"]
+                n_per = jax.tree.leaves(periods)[0].shape[0]
+                salts = jax.tree_util.tree_map_with_path(
+                    lambda p, _: zo_mod.path_salt(p, "['periods_zo']"),
+                    periods)
+                sizes = jax.tree.map(lambda a: a.size // n_per, periods)
+                xp, xm = run_periods_paired(
+                    periods, (xp, xm), cfg, rules, positions=positions,
+                    seed=seed, eps=lane.zo_eps, salts=salts, sizes=sizes,
+                    remat=remat, unroll=scan_unroll, enc_pair=enc_pair)
+                xp = jax.lax.stop_gradient(xp)
+                xm = jax.lax.stop_gradient(xm)
             losses = []
-            for x in (xp, xm):
-                x, _ = run_periods(bp_part["periods_bp"], x, cfg, rules,
-                                   positions=positions, mode="train",
-                                   enc_out=jax.lax.stop_gradient(enc_pair[0])
-                                   if enc_pair[0] is not None else None,
-                                   remat=remat, unroll=scan_unroll)
-                if n_img:
-                    x = x[:, n_img:]
-                losses.append(lm_loss(bp_part, x, batch["labels"],
-                                      batch["mask"], cfg, rules))
+            with jax.named_scope(zo_mod.TAIL):
+                for x in (xp, xm):
+                    x, _ = run_periods(
+                        bp_part["periods_bp"], x, cfg, rules,
+                        positions=positions, mode="train",
+                        enc_out=jax.lax.stop_gradient(enc_pair[0])
+                        if enc_pair[0] is not None else None,
+                        remat=remat, unroll=scan_unroll)
+                    if n_img:
+                        x = x[:, n_img:]
+                    losses.append(lm_loss(bp_part, x, batch["labels"],
+                                          batch["mask"], cfg, rules))
             return losses[0], losses[1]
 
         paired_loss_fn = paired_loss

@@ -40,6 +40,11 @@ Every phase exists in two dtype domains with identical semantics:
 Probes are keyed ``fold_in(fold_in(base_key, step), probe_id)`` with
 *global* probe ids in both domains — the fleet's probe-parallel layout
 is the single-process step with probe blocks assigned to workers.
+
+Inside the traced step every lane names its device time with the same
+four ``jax.named_scope`` phases of core/zo.py (``zo_perturb``,
+``zo_forward``, ``bp_tail``, ``zo_update``). The scopes are HLO
+metadata only; the step's numerics and buffers do not depend on them.
 """
 from __future__ import annotations
 
@@ -127,6 +132,7 @@ class Fp32Engine(UpdateEngine):
 
     # ---- ZO update (traced domain) ------------------------------------ #
     @staticmethod
+    @jax.named_scope(zo.UPDATE)
     def zo_apply(zo_part, terms: Sequence[Tuple[jax.Array, jax.Array]]):
         """theta <- cast(theta_f32 - sum_p coeff_p * z_p), probe order.
 
@@ -160,6 +166,7 @@ class Fp32Engine(UpdateEngine):
 
     # ---- BP-tail update (shared expression) --------------------------- #
     @staticmethod
+    @jax.named_scope(zo.TAIL)
     def tail_apply(bp_part, grad_avg, eta):
         """p <- cast(p_f32 - eta * g_f32); eta traced or host fp32."""
         return jax.tree.map(
@@ -183,7 +190,11 @@ class Fp32Engine(UpdateEngine):
 
     # ---- the train step (traced domain) ------------------------------- #
     def make_step(self, loss_fn: Callable[[Any, Any], jax.Array]):
-        """(state, batch, probe_mask fp32[n]) -> (state, metrics)."""
+        """(state, batch, probe_mask fp32[n]) -> (state, metrics).
+
+        metrics: ``loss``, ``zo_g`` (mean |g| over the probes) and
+        ``zo_dl`` fp32[n], each probe's signed L+ - L- before clipping
+        and masking (empty in ``full_bp``, which takes no probe)."""
         from .elastic import TrainState, merge
         lane = self.lane
         n = lane.zo_num_probes
@@ -207,7 +218,8 @@ class Fp32Engine(UpdateEngine):
                 loss, grads = jax.value_and_grad(
                     lambda bp: loss_fn(bp, batch))(bp_part)
                 new_params = self.tail_apply(bp_part, grads, eta_tail)
-                metrics = {"loss": loss, "zo_g": jnp.float32(0)}
+                metrics = {"loss": loss, "zo_g": jnp.float32(0),
+                           "zo_dl": jnp.zeros((0,), jnp.float32)}
                 return (TrainState(new_params, state.step + 1, state.seed),
                         metrics)
 
@@ -219,6 +231,7 @@ class Fp32Engine(UpdateEngine):
             tail_grad = None
             loss_acc = jnp.float32(0)
             g_acc = jnp.float32(0)
+            dls = []
             valid = jnp.maximum(jnp.sum(probe_mask), 1.0)
 
             zo_src = zo_part
@@ -253,11 +266,13 @@ class Fp32Engine(UpdateEngine):
                         zm = zo.perturb(zo_src, pk, -lane.zo_eps)
                         lm = loss_fn(merge(zm, bp_part), batch)
                 if has_tail:
-                    g_tail_i = jax.tree.map(
-                        lambda x, m=probe_mask[i]: m * x.astype(jnp.float32),
-                        g_tail_i)
-                    tail_grad = g_tail_i if tail_grad is None else \
-                        jax.tree.map(jnp.add, tail_grad, g_tail_i)
+                    with jax.named_scope(zo.TAIL):
+                        g_tail_i = jax.tree.map(
+                            lambda x, m=probe_mask[i]:
+                                m * x.astype(jnp.float32), g_tail_i)
+                        tail_grad = g_tail_i if tail_grad is None else \
+                            jax.tree.map(jnp.add, tail_grad, g_tail_i)
+                dls.append(lp - lm)
                 g = zo.projected_gradient(lp, lm, lane.zo_eps, lane.zo_clip)
                 g = g * probe_mask[i]
                 zo_terms.append((pk, eta_zo * g / valid))
@@ -266,13 +281,16 @@ class Fp32Engine(UpdateEngine):
 
             new_zo = self.zo_apply(zo_part, zo_terms)
             if has_tail:
-                tail_grad = jax.tree.map(lambda gt: gt / valid, tail_grad)
+                with jax.named_scope(zo.TAIL):
+                    tail_grad = jax.tree.map(lambda gt: gt / valid,
+                                             tail_grad)
                 new_bp = self.tail_apply(bp_part, tail_grad, eta_tail)
             else:
                 new_bp = bp_part
 
             new_params = merge(new_zo, new_bp)
-            metrics = {"loss": loss_acc / valid, "zo_g": g_acc / n}
+            metrics = {"loss": loss_acc / valid, "zo_g": g_acc / n,
+                       "zo_dl": jnp.stack(dls)}
             return TrainState(new_params, state.step + 1, state.seed), metrics
 
         return step
@@ -310,6 +328,7 @@ class Int8Engine(UpdateEngine):
         return gs * mask.astype(np.int32), valid
 
     # ---- ZO update (traced domain) ------------------------------------ #
+    @jax.named_scope(zo.UPDATE)
     def zo_apply(self, zo_part, terms: Sequence[Tuple[jax.Array, jax.Array]]):
         """theta <- clamp(theta - sum_p psr(g_p * z_p, shift), -127, 127).
 
@@ -368,10 +387,16 @@ class Int8Engine(UpdateEngine):
         from .int8 import perturb_int8
         from .int_loss import float_loss, int_loss_sign
         pzero = jnp.float32(self.p_zero)
-        zo_p = perturb_int8(zo_part, seed, +1, self.r_max, pzero)
-        logits_p, acts_p = forward({**zo_p, **bp_part}, batch["x"])
-        zo_m = perturb_int8(zo_part, seed, -1, self.r_max, pzero)
-        logits_m, _ = forward({**zo_m, **bp_part}, batch["x"])
+        # the model's forward holds its tail layers too: zo_forward
+        # covers the whole integer forward of both probes
+        with jax.named_scope(zo.PERTURB):
+            zo_p = perturb_int8(zo_part, seed, +1, self.r_max, pzero)
+        with jax.named_scope(zo.FORWARD):
+            logits_p, acts_p = forward({**zo_p, **bp_part}, batch["x"])
+        with jax.named_scope(zo.PERTURB):
+            zo_m = perturb_int8(zo_part, seed, -1, self.r_max, pzero)
+        with jax.named_scope(zo.FORWARD):
+            logits_m, _ = forward({**zo_m, **bp_part}, batch["x"])
         if self.loss_mode == "int":
             g = int_loss_sign(logits_p, logits_m, batch["y"])
         else:
@@ -381,6 +406,7 @@ class Int8Engine(UpdateEngine):
         return g, logits_p, acts_p
 
     # ---- BP tail ------------------------------------------------------- #
+    @jax.named_scope(zo.TAIL)
     def tail_updates(self, bp_part, acts, logits, labels):
         """One probe's NITI backward: {layer: upd int32} (not applied).
 
@@ -404,6 +430,7 @@ class Int8Engine(UpdateEngine):
         return upds
 
     @staticmethod
+    @jax.named_scope(zo.TAIL)
     def combine_tail(upds_list: Sequence[Dict[str, jax.Array]]):
         """Saturating-int8 combine of per-probe updates (wire-exact: the
         ledger carries this as the record's int8 tail payload)."""
@@ -415,6 +442,7 @@ class Int8Engine(UpdateEngine):
                 for n, u in acc.items()}
 
     @staticmethod
+    @jax.named_scope(zo.TAIL)
     def tail_apply(bp_part, combined: Dict[str, Any]):
         """w <- clamp(w - sum(upd), -127, 127); exponents unchanged."""
         from .int8 import QTensor
@@ -507,124 +535,13 @@ def engine_for(lane: LaneConfig, partition_fn: Optional[Callable] = None,
 
 
 # ------------------------------------------------------------------ #
-# phase profiler (diagnostic path, opt-in)
-# ------------------------------------------------------------------ #
-def profile_step_phases(engine: UpdateEngine, fn: Callable, state, batch,
-                        iters: int = 3) -> Dict[str, float]:
-    """Time the canonical phases one by one; returns {phase: mean_us}.
-
-    This is a *diagnostic* decomposition, deliberately separate from the
-    production train step: the production step is ONE jitted program
-    (host timers cannot see inside it), and re-building it as a chain of
-    separately-jitted phase programs re-fuses differently — FMA
-    contraction shifts the fp32 stream by ~1 ulp (the same reason
-    fleet/reference.py runs under ``LoopConfig(jit=False)``). So the
-    profiler builds its own per-phase programs — the same kernels the
-    real step traces — warms them, and times each with a
-    ``jax.block_until_ready`` device sync. The production step and its
-    numerics are untouched; the parameter state is never written.
-
-    ``fn`` is the lane's step builder argument: ``loss_fn`` for fp32
-    lanes, ``forward`` for int8. Spans land on the "engine" track of the
-    active recorder plus ``engine.phase.<name>_ms`` histograms.
-    """
-    from .. import obs
-    rec = obs.get()
-    lane = engine.lane
-    n = lane.zo_num_probes
-    params = state.params
-    base = jax.random.wrap_key_data(jnp.asarray(state.seed))
-    key = jax.random.fold_in(base, state.step)
-    out: Dict[str, float] = {}
-
-    def timed(name, f, *a):
-        jax.block_until_ready(f(*a))       # compile + warm
-        tot = 0.0
-        for _ in range(iters):
-            with rec.span(f"engine/{name}", track="engine") as sp:
-                t0 = obs.monotonic()
-                jax.block_until_ready(f(*a))
-                tot += obs.monotonic() - t0
-            rec.histogram(f"engine.phase.{name}_ms").observe(sp.dur_ns / 1e6)
-        out[name] = tot / iters * 1e6
-        return out[name]
-
-    timed("partition", lambda p: jax.tree_util.tree_leaves(
-        engine.partition(p)), params)
-    zo_part, bp_part = engine.partition(params)
-
-    if engine.numerics == "int8":
-        loss_fn = None
-        forward = fn
-        seeds = [prng.seed_from_key(jax.random.fold_in(key, i))
-                 for i in range(n)]
-
-        def probe_prog(zp, bp):
-            # loss-diff (the ternary sign) is fused into the probe pair
-            return jnp.stack([engine.probe_pair(forward, zp, bp, batch,
-                                                s)[0] for s in seeds])
-        gs = jax.jit(probe_prog)(zo_part, bp_part)
-        timed("probe", jax.jit(probe_prog), zo_part, bp_part)
-        mask = np.ones((n,), np.float32)
-        timed("coeff", lambda: engine.host_coeffs(
-            int(state.step), np.asarray(gs), mask))
-        terms = [(s, g) for s, g in zip(seeds, gs)]
-        timed("zo_update", jax.jit(
-            lambda zp: jax.tree_util.tree_leaves(engine.zo_apply(zp, terms))),
-            zo_part)
-        if engine.tail_fcs:
-            def tail_prog(bp, zp):
-                g, logits_p, acts_p = engine.probe_pair(forward, zp, bp,
-                                                        batch, seeds[0])
-                upds = engine.tail_updates(bp, acts_p, logits_p, batch["y"])
-                return jax.tree_util.tree_leaves(
-                    engine.tail_apply(bp, engine.combine_tail([upds])))
-            timed("bp_tail", jax.jit(tail_prog), bp_part, zo_part)
-        return out
-
-    loss_fn = fn
-    from .elastic import merge
-    keys = [jax.random.fold_in(key, i) for i in range(n)]
-
-    def probe_prog(zp, bp):
-        ls = []
-        for pk in keys:
-            ls.append(loss_fn(merge(zo.perturb(zp, pk, lane.zo_eps), bp),
-                              batch))
-            ls.append(loss_fn(merge(zo.perturb(zp, pk, -lane.zo_eps), bp),
-                              batch))
-        return jnp.stack(ls)
-    losses = np.asarray(jax.jit(probe_prog)(zo_part, bp_part))
-    timed("probe", jax.jit(probe_prog), zo_part, bp_part)
-    lp, lm = losses[0::2], losses[1::2]
-    timed("loss_diff", lambda: np.float32(lp) - np.float32(lm))
-    deltas = np.float32(lp) - np.float32(lm)
-    mask = np.ones((n,), np.float32)
-    timed("coeff", lambda: engine.host_coeffs(int(state.step), deltas, mask))
-    coeffs, _ = engine.host_coeffs(int(state.step), deltas, mask)
-    terms = [(pk, jnp.float32(c)) for pk, c in zip(keys, coeffs)]
-    timed("zo_update", jax.jit(
-        lambda zp: jax.tree_util.tree_leaves(engine.zo_apply(zp, terms))),
-        zo_part)
-    if jax.tree_util.tree_leaves(bp_part) and lane.lane == "elastic_zo":
-        eta = jnp.float32(tail_learning_rate(lane))
-
-        def tail_prog(bp, zp):
-            g = jax.grad(lambda b: loss_fn(merge(zp, b), batch))(bp)
-            return jax.tree_util.tree_leaves(engine.tail_apply(bp, g, eta))
-        timed("bp_tail", jax.jit(tail_prog), bp_part, zo_part)
-    return out
-
-
-# ------------------------------------------------------------------ #
 # step memory analysis (diagnostic path, opt-in)
 # ------------------------------------------------------------------ #
 def step_memory_analysis(step_fn: Callable, state, batch,
                          probe_mask) -> Optional[Dict[str, int]]:
     """Measured XLA footprint of ONE train step, without executing it.
 
-    The time profiler above cannot see memory and ``jax.live_arrays()``
-    cannot see inside a jitted program, so this is the measured twin of
+    ``jax.live_arrays()`` cannot see inside a jitted program, so this is the measured twin of
     the paper's analytic model (Eqs. 2-4 / 13-15): the step is lowered
     and compiled exactly as the production path runs it (same donation)
     and XLA's buffer assignment reports argument/output/temp/alias bytes

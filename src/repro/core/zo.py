@@ -11,6 +11,13 @@ kernels/zo_perturb.py for the explicit Pallas version of the same op.
 The projected gradient ``g = (l+ - l-)/(2 eps)`` is a *scalar*; in the
 data-parallel setting it is the only thing the ZO part of the model ever
 all-reduces (docs/design.md §2).
+
+The step's device time is split into four phases by ``jax.named_scope``
+names that ride into the compiled HLO's ``op_name`` metadata (they change
+nothing else), defined once below: ``PERTURB`` (here: ``perturb``,
+``perturb_slice``), ``FORWARD`` and ``TAIL`` (core/api.py's training
+loss), ``UPDATE`` (here: ``zo_update``; core/engine.py's ``zo_apply``).
+``bench/phases.py`` reads each phase's time back from a device trace.
 """
 from __future__ import annotations
 
@@ -21,6 +28,12 @@ import jax
 import jax.numpy as jnp
 
 from . import prng
+
+# the step's phase scopes (jax.named_scope names)
+PERTURB = "zo_perturb"
+FORWARD = "zo_forward"
+TAIL = "bp_tail"
+UPDATE = "zo_update"
 
 
 def path_salt(path, prefix: str = "") -> int:
@@ -38,6 +51,7 @@ def leaf_noise(key, path, leaf) -> jax.Array:
     return prng.normal(prng.seed_from_key(key), path_salt(path), leaf.shape)
 
 
+@jax.named_scope(PERTURB)
 def perturb_slice(pparams, salts, sizes, p_idx, seed, scale):
     """Perturb one scanned layer-slice so it matches the stacked leaf's
     noise exactly: z_slice = z_stacked[p_idx] via the flat-index offset.
@@ -53,6 +67,7 @@ def perturb_slice(pparams, salts, sizes, p_idx, seed, scale):
     return jax.tree.map(f, pparams, salts, sizes)
 
 
+@jax.named_scope(PERTURB)
 def perturb(params, key, scale: float | jax.Array):
     """theta + scale * z, z regenerated from `key` (leafwise)."""
     def f(path, leaf):
@@ -61,6 +76,7 @@ def perturb(params, key, scale: float | jax.Array):
     return jax.tree_util.tree_map_with_path(f, params)
 
 
+@jax.named_scope(UPDATE)
 def zo_update(params, key, step_size):
     """theta - step_size * z  (z replayed from `key`). step_size may be a
     traced scalar (eta * g)."""

@@ -45,10 +45,6 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir")
     ap.add_argument("--mesh", default="",
                     help="e.g. '2x2:data,model' to shard across local devices")
-    ap.add_argument("--profile-phases", action="store_true",
-                    help="time the engine's canonical step phases "
-                         "(separately-jitted diagnostic programs with "
-                         "device syncs; the production step is untouched)")
     obs.add_observability_args(ap)
     args = ap.parse_args(argv)
     obs.configure_from_args(args)
@@ -97,15 +93,6 @@ def main(argv=None):
                                ckpt_dir=args.ckpt_dir,
                                log_every=max(args.steps // 10, 1),
                                probe_drop_rate=args.probe_drop)
-    if args.profile_phases:
-        from ..core import engine as eng
-        phases = eng.profile_step_phases(
-            eng.engine_for(lane, model.partition_fn
-                           if hasattr(model, "partition_fn") else None),
-            model.loss_fn, state, batch_fn(0))
-        for name, us in phases.items():
-            obs.log("train", f"phase {name:10s} {us:10.1f} us")
-
     state, history = run(model.train_step, state, batch_fn, loop,
                          param_shardings=pshard)
     obs.log("train", f"done at step {int(state.step)}; "

@@ -25,6 +25,11 @@ so an uninstrumented process pays one attribute check per call site and
 allocates nothing. Hot loops hoist ``rec = obs.get()`` and guard
 device syncs with ``rec.enabled``.
 
+An armed span also opens a ``jax.profiler.TraceAnnotation`` of its
+name, so a ``jax.profiler`` trace holds it on the profiler's clock beside
+the device's operations. jax is imported at the first armed span, never
+at import, so ``repro.obs`` stays importable without it.
+
 The design constraint, pinned by tests/test_obs_inert.py: recording is
 **numerics-inert**. The recorder only ever wraps host-side control flow
 and never reaches inside a jitted program — an instrumented fleet chaos
@@ -147,12 +152,30 @@ class Histogram:
 # spans
 # ------------------------------------------------------------------ #
 
+_ANNOTATION = None          # jax.profiler.TraceAnnotation, False without jax
+
+
+def _annotation(name: str):
+    """A profiler annotation of ``name`` while a profiler records, else
+    None (no trace, or no jax)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _ANNOTATION = TraceAnnotation
+    if _ANNOTATION and _ANNOTATION.is_enabled():
+        return _ANNOTATION(name)
+    return None
+
 
 class _Span:
     """One live span; re-use via ``with rec.span(...) as sp`` and read
     ``sp.dur_ns`` after exit (e.g. to feed a histogram)."""
 
-    __slots__ = ("rec", "name", "track", "args", "t0", "depth", "dur_ns")
+    __slots__ = ("rec", "name", "track", "args", "t0", "depth", "dur_ns",
+                 "note")
 
     def __init__(self, rec: "Recorder", name: str, track: str, args):
         self.rec = rec
@@ -162,16 +185,22 @@ class _Span:
         self.t0 = 0
         self.depth = 0
         self.dur_ns = 0
+        self.note = None
 
     def __enter__(self):
         stack = self.rec._stack()
         self.depth = len(stack)
         stack.append(self)
+        self.note = _annotation(self.name)
+        if self.note is not None:
+            self.note.__enter__()
         self.t0 = perf_ns()
         return self
 
     def __exit__(self, *exc):
         self.dur_ns = perf_ns() - self.t0
+        if self.note is not None:
+            self.note.__exit__(*exc)
         self.rec._stack().pop()
         self.rec._finish(self)
         return False

@@ -127,9 +127,8 @@ def run(step_fn: Callable, state: TrainState,
             rec.histogram("train.step_ms").observe(sp.dur_ns / 1e6)
             toks = batch.get("tokens")      # absent for vision batches
             ntok = int(np.prod(toks.shape)) if hasattr(toks, "shape") else 0
-            if ntok and sp.dur_ns:
+            if ntok:
                 rec.counter("train.tokens").inc(ntok)
-                rec.gauge("train.tokens_per_s").set(ntok / (sp.dur_ns / 1e9))
             rec.gauge("train.loss").set(float(metrics["loss"]))
         if cfg.log_every and (step % cfg.log_every == 0
                               or step == cfg.total_steps - 1):

@@ -45,12 +45,11 @@ import jax.numpy as jnp
 ROOT = Path(__file__).resolve().parent
 
 LM_ARCH = "qwen3-4b"
-# Deepest elastic-ZO step at 4 x 1024 tokens that the TPU compiler fits
-# in a v5e's 15.75 GiB (16.91e9 bytes), compiled for a described v5e:
-# memory_analysis() peak (arguments + outputs + temporaries - aliased)
-# 35 layers 16.67e9 bytes, 34 layers 16.25e9; 36 layers is refused
-# (17.06e9 bytes of live buffers). At 28 layers that peak reads 15.13e9
-# where the chip measured 12.88e9 bytes in use.
+# The benchmark cell's depth. With whole perturbed copies of the ZO
+# parameters it was the deepest elastic-ZO step at 4 x 1024 tokens that a
+# v5e's 15.75 GiB held (36 layers were refused); perturbed inside the
+# layer scan, the 36-layer step compiles for a described v5e with
+# 8.82e9 bytes of arguments and 4.23e9 of temporaries.
 LM_LAYERS_ONE_CHIP = 35
 LM_ARGS = ["--arch", LM_ARCH, "--steps", "3", "--batch", "4",
            "--seq", "1024"]

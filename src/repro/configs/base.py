@@ -158,9 +158,8 @@ class LaneConfig:
     lr_decay_factor: float = 1.0
     lr_decay_every: int = 0
     bp_grad_mode: str = "avg_perturbed"   # avg_perturbed (Alg.1) | clean (3rd fwd)
-    # fused antithetic pair: run theta+eps*z and theta-eps*z through the layer
-    # stack together so FSDP weight gathers are paid once (beyond-paper;
-    # EXPERIMENTS.md §Perf). elastic_zo lane only.
+    # no effect: every LM elastic_zo build perturbs inside the layer scan
+    # (core/api.py); kept only for the benchmark's tests that still set it
     fused_probes: bool = False
     # int8 lane (Alg. 2)
     int8_loss_mode: str = "int"       # int (INT8*, Eq. 7-12) | float (sgn of fp32 diff)

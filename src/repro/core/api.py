@@ -14,8 +14,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs.base import LaneConfig, ModelConfig, ShapeConfig
 from ..models import transformer as tf
-from ..models.transformer import (embed, head_logits, lm_loss, make_caches,
-                                  run_encoder, run_periods)
+from ..models.transformer import (embed, head_logits, lm_loss,
+                                  lm_loss_scanned, make_caches, run_encoder,
+                                  run_periods)
 from ..sharding.rules import ShardingRules
 from . import elastic, zo as zo_mod
 from .elastic import TrainState
@@ -161,9 +162,14 @@ def build(cfg: ModelConfig, shape: ShapeConfig, lane: LaneConfig,
             return lm_loss(params, x, batch["labels"], batch["mask"], cfg,
                            rules)
 
+    # The elastic step perturbs where the weights are consumed: each layer's
+    # slice inside the scan, the embedding's gathered rows, one noise
+    # generation for both probe signs and no full-size perturbed copy. The
+    # ``clean`` tail mode's third forward needs the whole unperturbed-point
+    # pass and keeps the engine's materialised path (core/zo.py).
     paired_loss_fn = None
-    if lane.fused_probes and lane.lane == "elastic_zo":
-        from ..models.transformer import run_periods_paired
+    if lane.lane == "elastic_zo" and lane.bp_grad_mode == "avg_perturbed":
+        from ..models.transformer import embed_rows, run_periods_paired
         from . import prng
 
         def paired_loss(bp_part, zo_part, batch, key):
@@ -173,45 +179,49 @@ def build(cfg: ModelConfig, shape: ShapeConfig, lane: LaneConfig,
             positions = jnp.broadcast_to(
                 jnp.arange(S_tot, dtype=jnp.int32), (B, S_tot))
             seed = prng.seed_from_key(key)
-            rest = {k: v for k, v in zo_part.items() if k != "periods_zo"}
-            rest_p = zo_mod.perturb(rest, key, lane.zo_eps)
-            rest_m = zo_mod.perturb(rest, key, -lane.zo_eps)
+            eps = lane.zo_eps
+
+            tok_p, tok_m = zo_mod.perturb_rows_pair(zo_part, "embed",
+                                                    tokens, key, eps)
+            pos_p, pos_m = (zo_mod.perturb_rows_pair(
+                zo_part, "pos_embed", positions, key, eps)
+                if "pos_embed" in zo_part else (None, None))
+            # what is left (whisper's encoder) is perturbed whole
+            rest = {k: v for k, v in zo_part.items()
+                    if k not in ("periods_zo", "embed", "pos_embed")}
+            rest_p, rest_m = zo_mod.perturb_pair(rest, key, eps)
             with jax.named_scope(zo_mod.FORWARD):
                 enc_pair = (None, None)
-                if cfg.encoder_layers:      # whisper: encoder stays unfused
+                if cfg.encoder_layers:
                     enc_pair = (run_encoder(rest_p, batch["frames"], cfg,
                                             rules, unroll=scan_unroll),
                                 run_encoder(rest_m, batch["frames"], cfg,
                                             rules, unroll=scan_unroll))
-                xp = embed(rest_p, tokens, cfg, rules, positions,
-                           batch.get("img"))
-                xm = embed(rest_m, tokens, cfg, rules, positions,
-                           batch.get("img"))
+                xp = embed_rows(tok_p, rules, batch.get("img"), pos_p)
+                xm = embed_rows(tok_m, rules, batch.get("img"), pos_m)
                 periods = zo_part["periods_zo"]
-                n_per = jax.tree.leaves(periods)[0].shape[0]
-                salts = jax.tree_util.tree_map_with_path(
-                    lambda p, _: zo_mod.path_salt(p, "['periods_zo']"),
-                    periods)
-                sizes = jax.tree.map(lambda a: a.size // n_per, periods)
+                salts, sizes = zo_mod.slice_noise_spec(periods,
+                                                       "['periods_zo']")
                 xp, xm = run_periods_paired(
                     periods, (xp, xm), cfg, rules, positions=positions,
-                    seed=seed, eps=lane.zo_eps, salts=salts, sizes=sizes,
+                    seed=seed, eps=eps, salts=salts, sizes=sizes,
                     remat=remat, unroll=scan_unroll, enc_pair=enc_pair)
                 xp = jax.lax.stop_gradient(xp)
                 xm = jax.lax.stop_gradient(xm)
             losses = []
             with jax.named_scope(zo_mod.TAIL):
-                for x in (xp, xm):
+                for x, enc_out in zip((xp, xm), enc_pair):
                     x, _ = run_periods(
                         bp_part["periods_bp"], x, cfg, rules,
                         positions=positions, mode="train",
-                        enc_out=jax.lax.stop_gradient(enc_pair[0])
-                        if enc_pair[0] is not None else None,
+                        enc_out=None if enc_out is None
+                        else jax.lax.stop_gradient(enc_out),
                         remat=remat, unroll=scan_unroll)
                     if n_img:
                         x = x[:, n_img:]
-                    losses.append(lm_loss(bp_part, x, batch["labels"],
-                                          batch["mask"], cfg, rules))
+                    losses.append(lm_loss_scanned(
+                        bp_part, x, batch["labels"], batch["mask"], cfg,
+                        rules))
             return losses[0], losses[1]
 
         paired_loss_fn = paired_loss

@@ -238,8 +238,10 @@ class Fp32Engine(UpdateEngine):
             for i in range(n):
                 pk = jax.random.fold_in(key, i)
                 if paired_loss_fn is not None and has_tail:
-                    # fused antithetic pair: one layer traversal for both
-                    # probes; grad of the mean IS the averaged tail grad.
+                    # both probes perturbed where the weights are
+                    # consumed (core/api.py): z once per layer slice for
+                    # both signs, no full-size perturbed copy; the grad of
+                    # the mean IS the averaged tail grad.
                     def f(bp, _zo=zo_src, _pk=pk):
                         lp_, lm_ = paired_loss_fn(bp, _zo, batch, _pk)
                         return 0.5 * (lp_ + lm_), (lp_, lm_)

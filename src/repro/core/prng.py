@@ -36,6 +36,21 @@ def _fmix32(h):
     return h
 
 
+def _hash(seed: jax.Array, salt, idx) -> jax.Array:
+    h = idx * _PHI + jnp.asarray(salt, jnp.uint32)
+    h = _fmix32(h ^ seed.astype(jnp.uint32))
+    h = _fmix32(h + seed.astype(jnp.uint32) * _M2)
+    return h
+
+
+def _flat_index(shape, offset) -> jax.Array:
+    n = 1
+    for d in shape:
+        n *= int(d)
+    idx = jax.lax.iota(jnp.uint32, max(n, 1))
+    return (idx + jnp.asarray(offset, jnp.uint32)).reshape(shape or ())
+
+
 def uniform_bits(seed: jax.Array, salt, shape, offset=0) -> jax.Array:
     """uint32 hash bits for every element of `shape`.
 
@@ -44,21 +59,22 @@ def uniform_bits(seed: jax.Array, salt, shape, offset=0) -> jax.Array:
     bits(bigger_shape)[off + i]``, which is what lets a layer-scan slice
     reproduce exactly the noise of the stacked parameter leaf.
     """
-    n = 1
-    for d in shape:
-        n *= int(d)
-    idx = jax.lax.iota(jnp.uint32, max(n, 1))
-    idx = (idx + jnp.asarray(offset, jnp.uint32)).reshape(shape or ())
-    h = idx * _PHI + jnp.asarray(salt, jnp.uint32)
-    h = _fmix32(h ^ seed.astype(jnp.uint32))
-    h = _fmix32(h + seed.astype(jnp.uint32) * _M2)
-    return h
+    return _hash(seed, salt, _flat_index(shape, offset))
 
 
 def normal(seed: jax.Array, salt, shape, offset=0) -> jax.Array:
     """Standard normal fp32 via Box-Muller on two hashed uniform streams."""
-    b1 = uniform_bits(seed, 2 * np.uint32(salt) + np.uint32(1), shape, offset)
-    b2 = uniform_bits(seed, 2 * np.uint32(salt) + np.uint32(2), shape, offset)
+    return normal_at(seed, salt, _flat_index(shape, offset))
+
+
+def normal_at(seed: jax.Array, salt, idx: jax.Array) -> jax.Array:
+    """``normal`` at explicit flat indices (uint32 array, any shape):
+    ``normal_at(s, salt, idx) == normal(s, salt, shape).ravel()[idx]``
+    bitwise, which lets gathered embedding rows take their table's
+    noise without the table's z being generated."""
+    idx = idx.astype(jnp.uint32)
+    b1 = _hash(seed, 2 * np.uint32(salt) + np.uint32(1), idx)
+    b2 = _hash(seed, 2 * np.uint32(salt) + np.uint32(2), idx)
     # u1 in (0,1]: top 24 bits, offset so log() is finite
     u1 = (b1 >> np.uint32(8)).astype(jnp.float32) * np.float32(2 ** -24) \
         + np.float32(2 ** -25)

@@ -15,9 +15,19 @@ all-reduces (docs/design.md §2).
 The step's device time is split into four phases by ``jax.named_scope``
 names that ride into the compiled HLO's ``op_name`` metadata (they change
 nothing else), defined once below: ``PERTURB`` (here: ``perturb``,
-``perturb_slice``), ``FORWARD`` and ``TAIL`` (core/api.py's training
-loss), ``UPDATE`` (here: ``zo_update``; core/engine.py's ``zo_apply``).
-``bench/phases.py`` reads each phase's time back from a device trace.
+``perturb_pair``, ``perturb_slice_pair``, ``perturb_rows_pair``),
+``FORWARD`` and ``TAIL`` (core/api.py's training loss), ``UPDATE`` (here:
+``zo_update``; core/engine.py's ``zo_apply``). ``bench/phases.py`` reads
+each phase's time back from a device trace.
+
+Two ways to perturb. ``perturb`` builds a whole perturbed copy of a tree:
+the materialised path of the lanes whose ZO part has no layer scan
+(LeNet, ``full_zo``), the ``clean`` tail mode and the fleet worker's
+probe. The LM elastic step perturbs where the weights are consumed:
+``perturb_slice_pair`` inside the layer scan and ``perturb_rows_pair`` on
+the gathered embedding rows, each generating z once for both probe signs.
+Every element of a full-size copy is counted at trace time under the
+``zo.perturb.materialized_elements`` counter (docs/observability.md).
 """
 from __future__ import annotations
 
@@ -27,6 +37,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from . import prng
 
 # the step's phase scopes (jax.named_scope names)
@@ -51,25 +62,88 @@ def leaf_noise(key, path, leaf) -> jax.Array:
     return prng.normal(prng.seed_from_key(key), path_salt(path), leaf.shape)
 
 
+def _pair(leaf32, z, eps, dtype):
+    """(θ+εz, θ−εz), each rounded to `dtype` exactly as ``perturb`` rounds
+    it with scale ±eps."""
+    return ((leaf32 + eps * z).astype(dtype),
+            (leaf32 + (-eps) * z).astype(dtype))
+
+
+def _unzip(tree, pairs):
+    return (jax.tree.map(lambda _, pr: pr[0], tree, pairs),
+            jax.tree.map(lambda _, pr: pr[1], tree, pairs))
+
+
+def _count_materialized(tree, copies: int) -> None:
+    n = sum(int(leaf.size) for leaf in jax.tree.leaves(tree))
+    obs.get().counter("zo.perturb.materialized_elements").inc(copies * n)
+
+
+def slice_noise_spec(stacked, prefix: str):
+    """(salts, sizes) of a layer stack whose leaves lead with the layer
+    dim, for ``perturb_slice_pair``: each leaf's salt is that of its
+    path under `prefix` in the whole tree (the one ``perturb`` and the
+    update give it), each size the leaf's elements per layer."""
+    n = jax.tree.leaves(stacked)[0].shape[0]
+    salts = jax.tree_util.tree_map_with_path(
+        lambda p, _: path_salt(p, prefix), stacked)
+    return salts, jax.tree.map(lambda a: a.size // n, stacked)
+
+
 @jax.named_scope(PERTURB)
-def perturb_slice(pparams, salts, sizes, p_idx, seed, scale):
-    """Perturb one scanned layer-slice so it matches the stacked leaf's
-    noise exactly: z_slice = z_stacked[p_idx] via the flat-index offset.
+def perturb_slice_pair(pparams, salts, sizes, p_idx, seed, eps: float):
+    """Both probes' perturbed copies of one scanned layer-slice, from one
+    generation of its noise: z_slice = z_stacked[p_idx] via the flat-index
+    offset, so each copy equals the matching slice of ``perturb(stacked,
+    key, ±eps)`` bitwise.
 
     pparams: this period's param slice; salts/sizes: static pytrees (crc32
     of the *stacked* leaf path, per-period flat size); p_idx: traced scan
     index; seed: uint32 scalar (prng.seed_from_key of the probe key).
+    Returns (plus, minus), each shaped like pparams.
     """
     def f(leaf, salt, size):
         off = p_idx.astype(jnp.uint32) * jnp.uint32(size)
         z = prng.normal(seed, salt, leaf.shape, offset=off)
-        return (leaf.astype(jnp.float32) + scale * z).astype(leaf.dtype)
-    return jax.tree.map(f, pparams, salts, sizes)
+        return _pair(leaf.astype(jnp.float32), z, eps, leaf.dtype)
+    return _unzip(pparams, jax.tree.map(f, pparams, salts, sizes))
+
+
+@jax.named_scope(PERTURB)
+def perturb_rows_pair(params, name: str, rows, key, eps: float):
+    """Both probes' perturbed copies of the gathered rows
+    ``params[name][rows]`` of a 2-D top-level leaf (an embedding), with
+    the noise of flat index ``row * width + j``: bitwise
+    ``perturb(params, key, ±eps)[name][rows]``, with no full-size copy and
+    no z for the rows not gathered."""
+    table = params[name]
+    width = table.shape[-1]
+    flat = rows.astype(jnp.uint32)[..., None] * jnp.uint32(width) \
+        + jax.lax.iota(jnp.uint32, width)
+    z = prng.normal_at(prng.seed_from_key(key),
+                       path_salt((jax.tree_util.DictKey(name),)), flat)
+    x = jnp.take(table, rows, axis=0).astype(jnp.float32)
+    return _pair(x, z, eps, table.dtype)
+
+
+@jax.named_scope(PERTURB)
+def perturb_pair(params, key, eps: float):
+    """Both probes' whole perturbed copies of a tree from one generation
+    of each leaf's noise: (``perturb(params, key, eps)``, ``perturb(params,
+    key, -eps)``) bitwise."""
+    _count_materialized(params, 2)
+
+    def f(path, leaf):
+        return _pair(leaf.astype(jnp.float32), leaf_noise(key, path, leaf),
+                     eps, leaf.dtype)
+    return _unzip(params, jax.tree_util.tree_map_with_path(f, params))
 
 
 @jax.named_scope(PERTURB)
 def perturb(params, key, scale: float | jax.Array):
     """theta + scale * z, z regenerated from `key` (leafwise)."""
+    _count_materialized(params, 1)
+
     def f(path, leaf):
         z = leaf_noise(key, path, leaf)
         return (leaf.astype(jnp.float32) + scale * z).astype(leaf.dtype)

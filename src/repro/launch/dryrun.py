@@ -167,8 +167,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, lane: LaneConfig,
     cfg = get_arch(arch)
     shape = get_shape(shape_name)
     suffix = "" if strategy == "tp" else f"+{strategy}"
-    if lane.fused_probes:
-        suffix += "+fused"
     out = out_dir / f"{arch}__{shape_name}__{mesh_kind}{suffix}.json"
     if out.exists() and not force:
         return json.loads(out.read_text())
@@ -211,15 +209,13 @@ def main(argv=None):
     ap.add_argument("--no-depth-variants", action="store_true")
     ap.add_argument("--strategy", default="tp",
                     choices=["tp", "fsdp", "serve"])
-    ap.add_argument("--fused", action="store_true",
-                    help="fused antithetic-pair forward")
     ap.add_argument("--update-depth", action="store_true",
                     help="recompute only depth variants of existing cells")
     ap.add_argument("--out", default=str(RESULTS))
     args = ap.parse_args(argv)
     enable_compile_cache()
 
-    lane = LaneConfig(lane=args.lane, fused_probes=args.fused)
+    lane = LaneConfig(lane=args.lane)
     out_dir = Path(args.out)
     meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]
 

@@ -222,13 +222,15 @@ def run_periods(periods, x, cfg: ModelConfig, rules, *, positions, mode,
 def run_periods_paired(periods, x_pair, cfg: ModelConfig, rules, *,
                        positions, seed, eps, salts, sizes, remat=True,
                        unroll=False, enc_pair=(None, None)):
-    """Fused antithetic forward (§Perf iteration): advance the theta+eps*z
-    and theta-eps*z probes through the layer stack *together*, so each
-    layer's FSDP weight all-gather is paid once for both passes.
+    """Antithetic forward of the elastic step: advance the theta+eps*z and
+    theta-eps*z probes through the layer stack *together*. Each layer's
+    slice is perturbed where it is consumed, one noise generation for both
+    signs, so no full-size perturbed copy of the stack exists, and under
+    FSDP each layer's weight all-gather is paid once for both passes.
 
     Exactness: the per-slice noise equals the stacked-leaf noise by the
-    flat-offset property of core/prng.py, so the losses are bitwise the
-    math of the unfused path (up to fp reassociation). Train mode only.
+    flat-offset property of core/prng.py, so each perturbed slice is
+    bitwise that of ``zo.perturb`` on the stacked leaf. Train mode only.
     """
     from ..core import zo as zo_mod
     pattern = cfg.pattern
@@ -246,12 +248,16 @@ def run_periods_paired(periods, x_pair, cfg: ModelConfig, rules, *,
         if rules.strategy == "fsdp" and rules.mesh is not None:
             # gather each layer's weights ONCE (replicated), then derive the
             # +/- perturbed copies locally — this is the whole point of the
-            # fused pair: without it GSPMD gathers both perturbed copies.
+            # paired forward: without it GSPMD gathers both perturbed copies.
             pparams = jax.tree.map(
                 lambda a: rules.wsc(a, *((None,) * a.ndim)), pparams)
-        pp = zo_mod.perturb_slice(pparams, salts, sizes, p_idx, seed, eps)
+        pp, pm = zo_mod.perturb_slice_pair(pparams, salts, sizes, p_idx,
+                                           seed, eps)
+        # the perturbed pair is made once and then read by the matmuls:
+        # fused into a matmul as an operand, the noise would be generated
+        # again for every weight tile
+        pp, pm = jax.lax.optimization_barrier((pp, pm))
         hp = one(hp, pp, enc_pair[0])
-        pm = zo_mod.perturb_slice(pparams, salts, sizes, p_idx, seed, -eps)
         hm = one(hm, pm, enc_pair[1])
         return (hp, hm), None
 
@@ -273,11 +279,19 @@ def run_periods_paired(periods, x_pair, cfg: ModelConfig, rules, *,
 # --------------------------------------------------------------------- #
 def embed(params, tokens, cfg: ModelConfig, rules, positions,
           img_embeds=None):
-    x = jnp.take(params["embed"], tokens, axis=0)
+    pos_rows = (jnp.take(params["pos_embed"], positions, axis=0)
+                if "pos_embed" in params else None)
+    return embed_rows(jnp.take(params["embed"], tokens, axis=0), rules,
+                      img_embeds, pos_rows)
+
+
+def embed_rows(x, rules, img_embeds=None, pos_rows=None):
+    """The embedding from its gathered rows: token rows ``x`` [B, S, d],
+    image embeddings put first, learned position rows added."""
     if img_embeds is not None:
         x = jnp.concatenate([img_embeds.astype(x.dtype), x], axis=1)
-    if "pos_embed" in params:
-        x = x + jnp.take(params["pos_embed"], positions, axis=0)
+    if pos_rows is not None:
+        x = x + pos_rows
     return rules.act_btd(x)
 
 
@@ -310,26 +324,66 @@ def head_logits(params, x, cfg: ModelConfig, rules):
     return rules.logits(logits)
 
 
+def _chunk_nll(w, hc, yc, mc, Vp, rules):
+    """(summed masked NLL, summed mask) of one sequence chunk."""
+    logits = jnp.einsum("bsd,dv->bsv", hc, w)
+    logits = rules.logits(logits).astype(jnp.float32)
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    onehot = jax.nn.one_hot(yc, Vp, dtype=logits.dtype)
+    ll = jnp.sum(logits * onehot, axis=-1)
+    return jnp.sum((logz - ll) * mc), jnp.sum(mc)
+
+
 def lm_loss(params, x, labels, mask, cfg: ModelConfig, rules):
     """Chunked CE over the (vocab-sharded) logits. Returns scalar fp32."""
     B, S, _ = x.shape
-    Vp = cfg.padded_vocab
     n = CE_CHUNKS if S % CE_CHUNKS == 0 and S >= CE_CHUNKS else 1
     c = S // n
     h = rms_norm(x, params["final_norm"], cfg.norm_eps)
     tot = jnp.float32(0)
     cnt = jnp.float32(0)
     for i in range(n):
-        hc = jax.lax.slice_in_dim(h, i * c, (i + 1) * c, axis=1)
-        yc = jax.lax.slice_in_dim(labels, i * c, (i + 1) * c, axis=1)
-        mc = jax.lax.slice_in_dim(mask, i * c, (i + 1) * c, axis=1)
-        logits = jnp.einsum("bsd,dv->bsv", hc, params["unembed"])
-        logits = rules.logits(logits).astype(jnp.float32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        onehot = jax.nn.one_hot(yc, Vp, dtype=logits.dtype)
-        ll = jnp.sum(logits * onehot, axis=-1)
-        tot = tot + jnp.sum((logz - ll) * mc)
-        cnt = cnt + jnp.sum(mc)
+        t, k = _chunk_nll(
+            params["unembed"],
+            jax.lax.slice_in_dim(h, i * c, (i + 1) * c, axis=1),
+            jax.lax.slice_in_dim(labels, i * c, (i + 1) * c, axis=1),
+            jax.lax.slice_in_dim(mask, i * c, (i + 1) * c, axis=1),
+            cfg.padded_vocab, rules)
+        tot = tot + t
+        cnt = cnt + k
+    return tot / jnp.maximum(cnt, 1.0)
+
+
+def lm_loss_scanned(params, x, labels, mask, cfg: ModelConfig, rules):
+    """``lm_loss`` with the chunks as a scan whose body keeps only its
+    logits matmul for the backward (``dots_saveable``).
+
+    The head's gradient then accumulates chunk by chunk in one buffer,
+    where under ``lm_loss`` the compiler holds a full-size partial
+    gradient per chunk and sums them as it likes. The paired elastic
+    step, one program that differentiates both probes' heads, takes this
+    one: at qwen3-4b's untied head those partials were most of the step's
+    temporaries. The carry rounds the sum to the head's dtype once a
+    chunk, so the gradient is not bitwise ``lm_loss``'s; the other
+    training paths keep ``lm_loss`` and the streams their tests pin.
+    """
+    B, S, _ = x.shape
+    n = CE_CHUNKS if S % CE_CHUNKS == 0 and S >= CE_CHUNKS else 1
+    c = S // n
+    h = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    w = params["unembed"]
+
+    def chunk(carry, xs):
+        t, k = _chunk_nll(w, *xs, cfg.padded_vocab, rules)
+        return (carry[0] + t, carry[1] + k), None
+
+    def chunks(a):                      # [B, S, ...] -> [n, B, c, ...]
+        return jnp.swapaxes(a.reshape((B, n, c) + a.shape[2:]), 0, 1)
+
+    chunk = jax.checkpoint(chunk,
+                           policy=jax.checkpoint_policies.dots_saveable)
+    (tot, cnt), _ = jax.lax.scan(chunk, (jnp.float32(0), jnp.float32(0)),
+                                 (chunks(h), chunks(labels), chunks(mask)))
     return tot / jnp.maximum(cnt, 1.0)
 
 

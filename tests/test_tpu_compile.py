@@ -9,8 +9,13 @@ jnp reference.
 
 The topology is described inside a fixture, never at import: only the
 process that runs these tests loads the TPU compiler library.
+
+The elastic training step is compiled here too, at qwen3-4b widths with
+two layers, to read where the compiler puts the ZO noise.
 """
+import dataclasses
 import os
+import re
 
 import pytest
 
@@ -18,8 +23,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import get_arch
+from repro.configs import LaneConfig, ShapeConfig, get_arch
+from repro.core import api
+from repro.core.elastic import TrainState
 from repro.kernels import paged_attn, topk_mask, zo_fused_replay
+from repro.sharding.rules import ShardingRules
 
 QWEN = get_arch("qwen3-4b")
 SERVE_SLOTS, PAGE_SIZE, PAGES_PER_SEQ = 4, 16, 35     # 512 + 33 tokens
@@ -97,3 +105,79 @@ def test_zo_fused_replay_int8_compiles_on_a_lenet_leaf(compile_v5e):
             t, s, g, salt=7, r_max=3, p_zero=0.33, shift=1),
         ((784, 120), jnp.int8), ((3, 2), jnp.uint32), ((3, 2), jnp.int32))
     assert _has_kernel(c)
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_HEADER = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*(?:\(.*\))?.*\{\s*$")
+_CALLS = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _fusions(text):
+    """{fusion: (opcodes of every computation it calls, its op_name)} of
+    a compiled HLO text. Every computation header counts, those whose
+    parameter list holds ``/*index=N*/`` included."""
+    comps, calls, fusions, cur = {}, {}, {}, None
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m is None:
+            header = _HEADER.match(line)
+            if header:
+                cur = header.group(1)
+                comps.setdefault(cur, set())
+                calls.setdefault(cur, set())
+            continue
+        if cur is None:
+            continue
+        name, rest = m.groups()
+        head = rest.split("metadata=")[0]
+        op = re.search(r"\b([a-z][\w\-]*)\(", head)
+        comps[cur].add(op.group(1) if op else "")
+        calls[cur].update(_CALLS.findall(head))
+        if op and op.group(1) == "fusion":
+            op_name = _OP_NAME.search(rest)
+            fusions[name] = (_CALLS.findall(head),
+                             op_name.group(1) if op_name else "")
+    memo = {}
+
+    def ops(c):
+        if c not in memo:
+            memo[c] = set(comps.get(c, ()))
+            for d in calls.get(c, ()):
+                memo[c] |= ops(d)
+        return memo[c]
+    return {f: (set().union(set(), *(ops(c) for c in cs)), op_name)
+            for f, (cs, op_name) in fusions.items()}
+
+
+def test_elastic_step_keeps_the_noise_out_of_the_matmuls(one_chip,
+                                                         compile_v5e):
+    """No fusion of the step holds both the noise hash (u32 xor and
+    logical right shift) and a matmul, which would generate z again for
+    every weight tile; inside the layer scan each ZO leaf's noise is made
+    by one fusion, for both probe signs."""
+    cfg = dataclasses.replace(QWEN, num_layers=2)
+    B, S = 4, 1024
+    shape = ShapeConfig("t", seq_len=S, global_batch=B, kind="train")
+    lane = LaneConfig(lane="elastic_zo", bp_tail_layers=1)
+    m = api.build(cfg, shape, lane, ShardingRules(None, cfg, shape))
+
+    def spec(shape_, dtype):
+        return jax.ShapeDtypeStruct(shape_, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: spec(a.shape, a.dtype),
+                          m.abstract_params())
+    state = TrainState(params, spec((), jnp.int32), spec((2,), jnp.uint32))
+    batch = {"tokens": spec((B, S), jnp.int32),
+             "labels": spec((B, S), jnp.int32),
+             "mask": spec((B, S), jnp.float32)}
+    text = jax.jit(m.train_step, donate_argnums=(0,)).lower(
+        state, batch, spec((1,), jnp.float32)).compile().as_text()
+    noise = {f: v for f, v in _fusions(text).items()
+             if {"xor", "shift-right-logical"} <= v[0]}
+    assert noise
+    assert not [f for f, (ops, _) in noise.items()
+                if ops & {"convolution", "dot"}]
+    in_scan = [f for f, (_, op_name) in noise.items()
+               if "zo_perturb" in op_name and "while" in op_name]
+    assert len(in_scan) == len(jax.tree.leaves(params["periods_zo"]))

@@ -32,6 +32,10 @@ def cell_matrix():
                 cells.append((a.name, s.name, False,
                               "pure full-attention arch; 500k dense KV cache "
                               "excluded per assignment (docs/design.md §6)"))
+            elif a.is_mla and s.kind != "train":
+                cells.append((a.name, s.name, False,
+                              "latent attention is trained, not served: no "
+                              "latent paged KV cache yet (ROADMAP R10)"))
             else:
                 cells.append((a.name, s.name, True, ""))
     return cells

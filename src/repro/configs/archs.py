@@ -1,4 +1,4 @@
-"""The 10 assigned architectures, exact published configs.
+"""The 11 architectures, exact published configs.
 
 Sources are cited per-arch; shapes pairing per the assignment:
 train_4k / prefill_32k / decode_32k always; long_500k only for
@@ -86,7 +86,23 @@ JAMBA_V01_52B = ModelConfig(
     notes="[arXiv:2403.19887] attn:mamba 1:7, MoE every other layer, 16e top-2",
 )
 
+DEEPSEEK_V2_LITE = ModelConfig(
+    name="deepseek-v2-lite", family="moe",
+    num_layers=27, d_model=2048, num_heads=16, num_kv_heads=16, head_dim=192,
+    d_ff=10944, vocab_size=102400, rope_theta=10_000.0, norm_eps=1e-6,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, yarn_factor=40.0, yarn_original_max_pos=4096,
+    yarn_beta_fast=32.0, yarn_beta_slow=1.0, yarn_mscale=0.707,
+    yarn_mscale_all_dim=0.707,
+    num_experts=64, experts_per_token=6, moe_d_ff=1408, num_shared_experts=2,
+    norm_topk_prob=False, routed_scaling=1.0, first_dense_layers=1,
+    notes="[hf:deepseek-ai/DeepSeek-V2-Lite] MLA (kv_lora 512, no q-LoRA, "
+          "YaRN x40), 1 dense + 26 MoE layers: 64 routed experts top-6 "
+          "un-renormalised, 2 shared",
+)
+
 ARCHS = {c.name: c for c in (
     MISTRAL_NEMO_12B, PHI4_MINI_3_8B, QWEN3_4B, LLAMA3_8B, PHI35_MOE,
     MIXTRAL_8X7B, RWKV6_1_6B, WHISPER_SMALL, LLAVA_NEXT_34B, JAMBA_V01_52B,
+    DEEPSEEK_V2_LITE,
 )}

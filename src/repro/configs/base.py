@@ -31,10 +31,31 @@ class ModelConfig:
     qk_norm: bool = False
     sliding_window: int = 0          # 0 = full attention
     rope_theta: float = 1_000_000.0
-    # -- MoE --
-    num_experts: int = 0
+    # -- latent attention (MLA, DeepSeek-V2): kv_lora_rank > 0 selects it;
+    #    queries and keys take qk_nope + qk_rope dims a head, values v_head --
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # -- YaRN RoPE scaling (yarn_factor 0 = plain RoPE) --
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    # -- MoE (dropless: every routed slot of a held expert is computed) --
+    num_experts: int = 0             # the router's outputs
     experts_per_token: int = 0
-    capacity_factor: float = 1.25
+    moe_d_ff: int = 0                # routed expert width (0 = d_ff)
+    num_shared_experts: int = 0      # one SwiGLU of width shared * moe_d_ff
+    norm_topk_prob: bool = True      # renormalise the top-k gates
+    routed_scaling: float = 1.0      # gates times this when not renormalised
+    # this chip's share: experts [experts_first, experts_first + experts_held)
+    # of the router's num_experts (0 = all of them)
+    experts_held: int = 0
+    experts_first: int = 0
+    first_dense_layers: int = 0      # dense layers before the MoE periods
     # -- block pattern: one scan period; default = all-attention --
     block_pattern: Tuple[str, ...] = ()
     # -- MoE interleave within a period: indices of MoE FFN positions.
@@ -70,12 +91,28 @@ class ModelConfig:
 
     @property
     def num_periods(self) -> int:
+        """Periods of the scanned stack (the leading dense layers apart)."""
         p = len(self.pattern)
-        if self.num_layers % p:
+        n = self.num_layers - self.first_dense_layers
+        if n % p:
             raise ValueError(
-                f"{self.name}: num_layers={self.num_layers} is not a "
+                f"{self.name}: num_layers={self.num_layers} less "
+                f"{self.first_dense_layers} leading dense is not a "
                 f"multiple of the {p}-block pattern")
-        return self.num_layers // p
+        return n // p
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
 
     @property
     def padded_vocab(self) -> int:
@@ -94,10 +131,18 @@ class ModelConfig:
         hd = self.head_dim
         attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd \
             + self.num_heads * hd * d
+        if self.is_mla:
+            H, r = self.num_heads, self.kv_lora_rank
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            attn = d * H * qk + d * (r + self.qk_rope_head_dim) \
+                + r * H * (self.qk_nope_head_dim + self.v_head_dim) \
+                + H * self.v_head_dim * d
         dense_ffn = 3 * d * ff                       # SwiGLU
         if self.is_moe:
-            e = self.experts_per_token if active_only else self.num_experts
-            moe_ffn = e * 3 * d * ff + d * self.num_experts  # + router
+            e = self.experts_per_token if active_only else self.held_experts
+            eff = self.expert_d_ff
+            moe_ffn = e * 3 * d * eff + d * self.num_experts  # + router
+            moe_ffn += 3 * d * eff * self.num_shared_experts
         else:
             moe_ffn = dense_ffn
         d_inner = self.ssm_expand * d
@@ -109,8 +154,8 @@ class ModelConfig:
                  + d_inner * d)                      # out proj
         # rwkv6: time-mix ~5 d² (r,k,v,g,o) + channel-mix (k: d->ff, v: ff->d, r: d->d)
         rwkv = 5 * d * d + (d * self.d_ff + self.d_ff * d + d * d)
-        per_layer = 0
-        for li in range(self.num_layers):
+        per_layer = self.first_dense_layers * (attn + dense_ffn)
+        for li in range(self.num_layers - self.first_dense_layers):
             kind = self.pattern[li % len(self.pattern)]
             if kind == ATTN:
                 per_layer += attn
@@ -180,7 +225,8 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
     """A tiny same-family config for CPU smoke tests."""
     pattern = cfg.pattern
     small = dict(
-        num_layers=len(pattern) if len(pattern) > 1 else 2,
+        num_layers=cfg.first_dense_layers
+        + (len(pattern) if len(pattern) > 1 else 2),
         d_model=64,
         num_heads=4,
         num_kv_heads=min(cfg.num_kv_heads, 2) if cfg.num_kv_heads < cfg.num_heads else 4,
@@ -195,6 +241,13 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         encoder_seq=16 if cfg.encoder_seq else 0,
         num_image_tokens=8 if cfg.num_image_tokens else 0,
         sliding_window=16 if cfg.sliding_window else 0,
+        kv_lora_rank=32 if cfg.kv_lora_rank else 0,
+        qk_nope_head_dim=16 if cfg.kv_lora_rank else 0,
+        qk_rope_head_dim=8 if cfg.kv_lora_rank else 0,
+        v_head_dim=16 if cfg.kv_lora_rank else 0,
+        moe_d_ff=32 if cfg.moe_d_ff else 0,
+        experts_held=0,
+        experts_first=0,
         name=cfg.name + "-smoke",
     )
     small.update(overrides)

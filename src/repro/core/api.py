@@ -78,8 +78,11 @@ def split_caches(caches, cfg: ModelConfig, lane: LaneConfig):
 def build(cfg: ModelConfig, shape: ShapeConfig, lane: LaneConfig,
           rules: ShardingRules, remat: bool = True,
           scan_unroll: bool = False) -> BuiltModel:
+    if cfg.is_mla and shape.kind in ("prefill", "decode"):
+        raise NotImplementedError(tf.MLA_SERVING)
     K = tail_periods(cfg, lane)
     PZ = cfg.num_periods - K
+    lead_cfg = tf.lead_config(cfg)
     n_img = cfg.num_image_tokens
     dtype = jnp.dtype(cfg.dtype)
     # ElasticZO: the ZO head is never differentiated — cut the grad chain so
@@ -108,6 +111,10 @@ def build(cfg: ModelConfig, shape: ShapeConfig, lane: LaneConfig,
             enc_out = run_encoder(params, frames, cfg, rules,
                                   unroll=scan_unroll)
         x = embed(params, tokens, cfg, rules, positions, img_embeds)
+        if "lead" in params:                 # leading dense layers
+            x, _ = run_periods(params["lead"], x, lead_cfg, rules,
+                               positions=positions, mode=mode, remat=remat,
+                               unroll=scan_unroll)
         x, ncz = run_periods(params["periods_zo"], x, cfg, rules,
                              positions=positions, mode=mode,
                              caches=None if caches is None else caches["zo"],
@@ -188,7 +195,7 @@ def build(cfg: ModelConfig, shape: ShapeConfig, lane: LaneConfig,
                 if "pos_embed" in zo_part else (None, None))
             # what is left (whisper's encoder) is perturbed whole
             rest = {k: v for k, v in zo_part.items()
-                    if k not in ("periods_zo", "embed", "pos_embed")}
+                    if k not in ("periods_zo", "lead", "embed", "pos_embed")}
             rest_p, rest_m = zo_mod.perturb_pair(rest, key, eps)
             with jax.named_scope(zo_mod.FORWARD):
                 enc_pair = (None, None)
@@ -197,15 +204,22 @@ def build(cfg: ModelConfig, shape: ShapeConfig, lane: LaneConfig,
                                             rules, unroll=scan_unroll),
                                 run_encoder(rest_m, batch["frames"], cfg,
                                             rules, unroll=scan_unroll))
-                xp = embed_rows(tok_p, rules, batch.get("img"), pos_p)
-                xm = embed_rows(tok_m, rules, batch.get("img"), pos_m)
-                periods = zo_part["periods_zo"]
-                salts, sizes = zo_mod.slice_noise_spec(periods,
-                                                       "['periods_zo']")
-                xp, xm = run_periods_paired(
-                    periods, (xp, xm), cfg, rules, positions=positions,
-                    seed=seed, eps=eps, salts=salts, sizes=sizes,
-                    remat=remat, unroll=scan_unroll, enc_pair=enc_pair)
+                x_pair = (embed_rows(tok_p, rules, batch.get("img"), pos_p),
+                          embed_rows(tok_m, rules, batch.get("img"), pos_m))
+                for group, gcfg in (("lead", lead_cfg), ("periods_zo", cfg)):
+                    if group not in zo_part:
+                        continue
+                    salts, sizes = zo_mod.slice_noise_spec(
+                        zo_part[group], f"['{group}']")
+                    x_pair, stats = run_periods_paired(
+                        zo_part[group], x_pair, gcfg, rules,
+                        positions=positions, seed=seed, eps=eps, salts=salts,
+                        sizes=sizes, remat=remat, unroll=scan_unroll,
+                        enc_pair=enc_pair)
+                counts = {} if stats is None else {
+                    "moe.held_rows": stats[0],
+                    "moe.max_expert_rows": stats[1]}
+                xp, xm = x_pair
                 xp = jax.lax.stop_gradient(xp)
                 xm = jax.lax.stop_gradient(xm)
             losses = []
@@ -222,7 +236,7 @@ def build(cfg: ModelConfig, shape: ShapeConfig, lane: LaneConfig,
                     losses.append(lm_loss_scanned(
                         bp_part, x, batch["labels"], batch["mask"], cfg,
                         rules))
-            return losses[0], losses[1]
+            return losses[0], losses[1], counts
 
         paired_loss_fn = paired_loss
 

@@ -4,7 +4,7 @@ Parameter partition is structural: the LM parameter tree stores the layer
 stack as two period-stacks, ``periods_zo`` (first P-K periods) and
 ``periods_bp`` (last K periods). Lanes assign top-level groups:
 
-  elastic_zo : ZO = {embed, pos_embed, encoder, periods_zo}
+  elastic_zo : ZO = {embed, pos_embed, encoder, lead, periods_zo}
                BP = {periods_bp, final_norm, unembed}
   full_zo    : ZO = everything            (paper baseline, C = L)
   full_bp    : BP = everything            (paper baseline, C = 0)
@@ -36,7 +36,7 @@ import jax
 from ..configs.base import LaneConfig
 from .engine import Fp32Engine
 
-ZO_GROUPS = ("embed", "pos_embed", "encoder", "periods_zo")
+ZO_GROUPS = ("embed", "pos_embed", "encoder", "lead", "periods_zo")
 BP_GROUPS = ("periods_bp", "final_norm", "unembed")
 
 

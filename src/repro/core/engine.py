@@ -194,7 +194,10 @@ class Fp32Engine(UpdateEngine):
 
         metrics: ``loss``, ``zo_g`` (mean |g| over the probes) and
         ``zo_dl`` fp32[n], each probe's signed L+ - L- before clipping
-        and masking (empty in ``full_bp``, which takes no probe)."""
+        and masking (empty in ``full_bp``, which takes no probe); the
+        in-scan path adds the paired loss's counts over the probes
+        (``moe.held_rows``, ``moe.max_expert_rows`` for an MoE stack,
+        ``merge_counts``)."""
         from .elastic import TrainState, merge
         lane = self.lane
         n = lane.zo_num_probes
@@ -235,6 +238,7 @@ class Fp32Engine(UpdateEngine):
             valid = jnp.maximum(jnp.sum(probe_mask), 1.0)
 
             zo_src = zo_part
+            counts = {}             # device-side counts of the paired loss
             for i in range(n):
                 pk = jax.random.fold_in(key, i)
                 if paired_loss_fn is not None and has_tail:
@@ -243,10 +247,11 @@ class Fp32Engine(UpdateEngine):
                     # both signs, no full-size perturbed copy; the grad of
                     # the mean IS the averaged tail grad.
                     def f(bp, _zo=zo_src, _pk=pk):
-                        lp_, lm_ = paired_loss_fn(bp, _zo, batch, _pk)
-                        return 0.5 * (lp_ + lm_), (lp_, lm_)
-                    (_, (lp, lm)), g_tail_i = jax.value_and_grad(
+                        lp_, lm_, c_ = paired_loss_fn(bp, _zo, batch, _pk)
+                        return 0.5 * (lp_ + lm_), (lp_, lm_, c_)
+                    (_, (lp, lm, c)), g_tail_i = jax.value_and_grad(
                         f, has_aux=True)(bp_part)
+                    counts = merge_counts(counts, c)
                 else:
                     zp = zo.perturb(zo_src, pk, lane.zo_eps)
                     if has_tail:
@@ -292,10 +297,24 @@ class Fp32Engine(UpdateEngine):
 
             new_params = merge(new_zo, new_bp)
             metrics = {"loss": loss_acc / valid, "zo_g": g_acc / n,
-                       "zo_dl": jnp.stack(dls)}
+                       "zo_dl": jnp.stack(dls), **counts}
             return TrainState(new_params, state.step + 1, state.seed), metrics
 
         return step
+
+
+def merge_counts(acc: dict, new: dict) -> dict:
+    """Probe counts merged: a ``max_`` count keeps the largest, the
+    others add."""
+    out = dict(acc)
+    for k, v in new.items():
+        if k not in out:
+            out[k] = v
+        elif k.rsplit(".", 1)[-1].startswith("max_"):
+            out[k] = jnp.maximum(out[k], v)
+        else:
+            out[k] = out[k] + v
+    return out
 
 
 # ------------------------------------------------------------------ #

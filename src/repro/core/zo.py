@@ -18,7 +18,11 @@ nothing else), defined once below: ``PERTURB`` (here: ``perturb``,
 ``perturb_pair``, ``perturb_slice_pair``, ``perturb_rows_pair``),
 ``FORWARD`` and ``TAIL`` (core/api.py's training loss), ``UPDATE`` (here:
 ``zo_update``; core/engine.py's ``zo_apply``). ``bench/phases.py`` reads
-each phase's time back from a device trace.
+each phase's time back from a device trace. Three more scopes nest inside
+the phases and name the parts of a latent-attention MoE layer: ``MLA``
+(models/layers.py: the latent projections and attention), ``MOE_ROUTE``
+(models/moe.py: router, top-k, sort, gather, scatter and combine) and
+``MOE_EXPERTS`` (the held experts' grouped matmuls).
 
 Two ways to perturb. ``perturb`` builds a whole perturbed copy of a tree:
 the materialised path of the lanes whose ZO part has no layer scan
@@ -45,6 +49,10 @@ PERTURB = "zo_perturb"
 FORWARD = "zo_forward"
 TAIL = "bp_tail"
 UPDATE = "zo_update"
+# scopes inside the phases (a latent-attention MoE layer's parts)
+MLA = "mla"
+MOE_ROUTE = "moe_route"
+MOE_EXPERTS = "moe_experts"
 
 
 def path_salt(path, prefix: str = "") -> int:

@@ -1,4 +1,5 @@
-"""Core transformer layers: norms, RoPE, GQA attention (TP-planned), SwiGLU.
+"""Core transformer layers: norms, RoPE (plain and YaRN), GQA attention
+(TP-planned), latent attention (MLA), SwiGLU.
 
 Attention supports two sharding plans chosen by ``ShardingRules``:
 
@@ -21,10 +22,13 @@ import math
 import zlib
 from typing import Optional, Tuple
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from ..core.zo import MLA
 
 Q_CHUNK = 4096          # query block size for chunked attention
 
@@ -94,6 +98,120 @@ def rope(x, positions, theta):
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+def yarn_correction_range(cfg: ModelConfig, dim: int):
+    """(low, high) rotary-pair indices between which YaRN ramps from
+    extrapolated to interpolated frequencies (DeepSeek-V2's
+    ``yarn_find_correction_range``)."""
+    def corr(rot):
+        return dim * math.log(cfg.yarn_original_max_pos
+                              / (rot * 2 * math.pi)) \
+            / (2 * math.log(cfg.rope_theta))
+    low = math.floor(corr(cfg.yarn_beta_fast))
+    high = math.ceil(corr(cfg.yarn_beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def rope_inv_freq(cfg: ModelConfig, dim: int) -> np.ndarray:
+    """Inverse frequencies of `dim` rotary dims: plain RoPE, or YaRN's
+    blend of the extrapolated and the factor-interpolated ones."""
+    base = np.float32(cfg.rope_theta) \
+        ** (np.arange(0, dim, 2, dtype=np.float32) / np.float32(dim))
+    extra = np.float32(1.0) / base
+    if cfg.yarn_factor <= 0:
+        return extra
+    low, high = yarn_correction_range(cfg, dim)
+    ramp = (np.arange(dim // 2, dtype=np.float32) - np.float32(low)) \
+        / np.float32(max(high - low, 1e-3))
+    keep = np.float32(1.0) - np.clip(ramp, 0.0, 1.0)      # 1 = extrapolate
+    inter = np.float32(1.0) / (np.float32(cfg.yarn_factor) * base)
+    return (inter * (np.float32(1.0) - keep) + extra * keep) \
+        .astype(np.float32)
+
+
+def rope_mscale(cfg: ModelConfig) -> float:
+    """YaRN's factor on cos and sin (1 without YaRN)."""
+    if cfg.yarn_factor <= 0:
+        return 1.0
+    return yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale) \
+        / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(qk_nope + qk_rope)^-1/2, times YaRN's mscale squared."""
+    s = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.yarn_factor > 0 and cfg.yarn_mscale_all_dim:
+        s *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return s
+
+
+def rope_rotate(x, positions, inv_freq, mscale: float = 1.0):
+    """Rotate-half RoPE of x [B, S, H, D] at `positions` [B, S] with the
+    given inverse frequencies (D / 2 of them); cos and sin times
+    `mscale`."""
+    ang = positions[..., None].astype(jnp.float32) * jnp.asarray(inv_freq)
+    cos = (jnp.cos(ang) * mscale)[:, :, None, :]
+    sin = (jnp.sin(ang) * mscale)[:, :, None, :]
+    half = x.shape[-1] // 2
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+# --------------------------------------------------------------------- #
+# latent attention (MLA; DeepSeek-V2, arXiv:2405.04434 section 2.1)
+# --------------------------------------------------------------------- #
+def init_mla(key, cfg: ModelConfig, dtype):
+    d, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope, rot, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                     cfg.v_head_dim)
+    return {
+        "wq": dense_init(subkey(key, "wq"), (d, H, nope + rot), dtype),
+        "wkv_a": dense_init(subkey(key, "wkv_a"), (d, r + rot), dtype),
+        "kv_norm": jnp.ones((r,), dtype),
+        "wkv_b": dense_init(subkey(key, "wkv_b"), (r, H, nope + vd), dtype),
+        "wo": dense_init(subkey(key, "wo"), (H, vd, d), dtype,
+                         fan_in=H * vd),
+    }
+
+
+@jax.named_scope(MLA)
+def mla_attention(p, x, cfg: ModelConfig, rules, positions):
+    """Causal latent attention over the whole sequence, keys and values
+    decompressed from the latent: x [B, S, d] -> [B, S, d].
+
+    q = x Wq splits per head into nope and rope dims; the latent c and
+    one rope key shared by the heads come from x Wkv_a; c is normalised
+    and Wkv_b gives each head's nope key and value. The rope dims rotate
+    by YaRN's frequencies (rotate-half pairing)."""
+    B, S, _ = x.shape
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rot = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    ckv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
+    c = rms_norm(ckv[..., :r], p["kv_norm"], cfg.norm_eps)
+    kv = jnp.einsum("bsr,rhk->bshk", c, p["wkv_b"])
+    inv, msc = rope_inv_freq(cfg, rot), rope_mscale(cfg)
+    q_pe = rope_rotate(q[..., nope:], positions, inv, msc)
+    k_pe = rope_rotate(ckv[..., None, r:], positions, inv, msc)
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (B, S, H, rot))], axis=-1)
+    v = kv[..., nope:]
+    s = jnp.einsum("bqhk,bthk->bhqt", q, k,
+                   preferred_element_type=jnp.float32) \
+        * mla_softmax_scale(cfg)
+    causal = positions[:, None, None, :] <= positions[:, None, :, None]
+    w = jax.nn.softmax(jnp.where(causal, s, -1e30), axis=-1)
+    o = jnp.einsum("bhqt,bthv->bqhv", w.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32).astype(v.dtype)
+    return rules.act_btd(jnp.einsum("bqhv,hvd->bqd", o, p["wo"]))
 
 
 # --------------------------------------------------------------------- #

@@ -1,29 +1,35 @@
 """LM stacks: decoder-only (dense/MoE/SSM/hybrid), enc-dec (Whisper), VLM.
 
 Layout: params = {embed, periods (stacked, leading dim = num_periods),
-final_norm, unembed [, pos_embed, encoder]}. The layer stack runs as a
-``lax.scan`` over periods; a period is one repetition of
-``cfg.block_pattern`` (1 layer for uniform archs, 8 for Jamba). Caches ride
-the scan as xs/ys. docs/design.md §7 explains the cost-extrapolation contract:
-the scan body is identical at any depth, so the dry-run can compile
-depth-2/depth-4 variants to recover exact per-layer costs.
+final_norm, unembed [, lead, pos_embed, encoder]}. The layer stack runs as
+a ``lax.scan`` over periods; a period is one repetition of
+``cfg.block_pattern`` (1 layer for uniform archs, 8 for Jamba). Leading
+dense layers (``cfg.first_dense_layers``, DeepSeek's layer 0) are a stack
+of their own, ``lead``, scanned before the periods with the dense view of
+the config (``lead_config``). Caches ride the scan as xs/ys.
+docs/design.md §7 explains the cost-extrapolation contract: the scan body
+is identical at any depth, so the dry-run can compile depth-2/depth-4
+variants to recover exact per-layer costs.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 
 from ..configs.base import ATTN, MAMBA, RWKV, ModelConfig
-from .layers import (attention, dense_init, init_attention, init_mlp, mlp,
-                     rms_norm, subkey)
+from .layers import (attention, dense_init, init_attention, init_mla,
+                     init_mlp, mla_attention, mlp, rms_norm, subkey)
 from .moe import init_moe, moe_ffn
 from .ssm import (init_mamba_block, init_mamba_state, init_rwkv_block,
                   init_rwkv_state, mamba_block, rwkv_block)
 
 CE_CHUNKS = 4            # sequence chunks for the cross-entropy epilogue
+MLA_SERVING = ("latent attention (MLA) is trained but not served: prefill "
+               "and decode need a latent paged KV cache (ROADMAP R10)")
 
 
 # --------------------------------------------------------------------- #
@@ -31,6 +37,15 @@ CE_CHUNKS = 4            # sequence chunks for the cross-entropy epilogue
 # --------------------------------------------------------------------- #
 def _ffn_is_moe(cfg: ModelConfig, pos_in_period: int) -> bool:
     return cfg.is_moe and (pos_in_period % cfg.moe_every == cfg.moe_offset)
+
+
+def lead_config(cfg: ModelConfig) -> ModelConfig:
+    """The leading dense layers' view of the config: same attention, a
+    dense SwiGLU of width ``d_ff``."""
+    return dataclasses.replace(
+        cfg, num_layers=cfg.first_dense_layers, first_dense_layers=0,
+        num_experts=0, experts_per_token=0, num_shared_experts=0,
+        block_pattern=(ATTN,))
 
 
 def init_block(key, cfg: ModelConfig, kind: str, pos: int, dtype,
@@ -41,7 +56,8 @@ def init_block(key, cfg: ModelConfig, kind: str, pos: int, dtype,
     p: Dict[str, Any] = {}
     if kind == ATTN:
         p["ln_attn"] = jnp.ones((d,), dtype)
-        p["attn"] = init_attention(subkey(key, "attn"), cfg, dtype)
+        p["attn"] = (init_mla if cfg.is_mla else init_attention)(
+            subkey(key, "attn"), cfg, dtype)
         if cross_attn:
             p["ln_cross"] = jnp.ones((d,), dtype)
             p["cross"] = init_attention(subkey(key, "cross"), cfg, dtype)
@@ -73,6 +89,10 @@ def init_lm(key, cfg: ModelConfig, max_seq: int, dtype=None):
         "final_norm": jnp.ones((d,), dtype),
         "unembed": dense_init(subkey(key, "unembed"), (d, Vp), dtype),
     }
+    if cfg.first_dense_layers:
+        lcfg = lead_config(cfg)
+        params["lead"] = jax.vmap(lambda k: init_period(k, lcfg, dtype))(
+            jax.random.split(subkey(key, "lead"), cfg.first_dense_layers))
     if cfg.rope_theta <= 0:                      # learned absolute positions
         params["pos_embed"] = dense_init(subkey(key, "pos"), (max_seq, d), dtype)
     if cfg.encoder_layers:
@@ -96,23 +116,28 @@ def apply_block(p, x, cfg: ModelConfig, kind: str, pos: int, rules, *,
                 positions, mode: str, cache=None, cache_len=None,
                 enc_out=None, cross_cache=None, causal: bool = True,
                 paged=None, full_kv: bool = False):
-    """Returns (x, new_cache_entry).
+    """Returns (x, new_cache_entry, moe_stats).
 
     paged: (page_table, seq_lens) — decode against the paged KV pool
     (serve subsystem); full_kv: prefill returns the un-rolled full-length
     KV even for SWA archs (the paged pool stores absolute positions and
-    applies the window as a mask instead of a ring buffer).
+    applies the window as a mask instead of a ring buffer). moe_stats:
+    (held rows, most rows on one expert) of an MoE FFN, else None.
     """
     if kind == RWKV:
         state = cache if mode == "decode" else None
         x, st = rwkv_block(p["rwkv"], x, cfg, rules, state)
-        return x, (st if mode in ("decode", "prefill") else None)
+        return x, (st if mode in ("decode", "prefill") else None), None
 
     new_cache: Dict[str, Any] = {}
     if kind == ATTN:
         h = rms_norm(x, p["ln_attn"], cfg.norm_eps)
         window = cfg.sliding_window
-        if mode == "decode":
+        if cfg.is_mla:
+            if mode != "train":
+                raise NotImplementedError(MLA_SERVING)
+            y = mla_attention(p["attn"], h, cfg, rules, positions)
+        elif mode == "decode":
             y, kv = attention(p["attn"], h, cfg, rules, positions,
                               causal=True, window=window,
                               cache=(cache["k"], cache["v"]),
@@ -149,9 +174,13 @@ def apply_block(p, x, cfg: ModelConfig, kind: str, pos: int, rules, *,
             new_cache.update(st)
 
     h = rms_norm(x, p["ln_ffn"], cfg.norm_eps)
-    y = moe_ffn(p["moe"], h, cfg, rules) if "moe" in p else mlp(p["mlp"], h, rules)
+    stats = None
+    if "moe" in p:
+        y, stats = moe_ffn(p["moe"], h, cfg, rules)
+    else:
+        y = mlp(p["mlp"], h, rules)
     x = rules.act_btd(x + y)
-    return x, (new_cache if mode in ("decode", "prefill") else None)
+    return x, (new_cache if mode in ("decode", "prefill") else None), stats
 
 
 def _cross_kv(p, enc_out, cfg: ModelConfig, rules):
@@ -187,7 +216,7 @@ def run_periods(periods, x, cfg: ModelConfig, rules, *, positions, mode,
         new_caches = []
         for i, kind in enumerate(pattern):
             ci = None if pcache is None else pcache[i]
-            h, nc = apply_block(
+            h, nc, _ = apply_block(
                 pparams[f"blk{i}"], h, cfg, kind, i, rules,
                 positions=positions, mode=mode, cache=ci,
                 cache_len=cache_len, enc_out=enc_out, cross_cache=ci,
@@ -231,16 +260,23 @@ def run_periods_paired(periods, x_pair, cfg: ModelConfig, rules, *,
     Exactness: the per-slice noise equals the stacked-leaf noise by the
     flat-offset property of core/prng.py, so each perturbed slice is
     bitwise that of ``zo.perturb`` on the stacked leaf. Train mode only.
+
+    Returns (x_pair, stats): stats is None without MoE FFNs, else the
+    rows routed to held experts summed over the layers and both probes
+    and the most rows on one held expert in one layer (int32).
     """
     from ..core import zo as zo_mod
     pattern = cfg.pattern
 
     def one(h, pparams, enc_out):
+        stats = []
         for i, kind in enumerate(pattern):
-            h, _ = apply_block(pparams[f"blk{i}"], h, cfg, kind, i, rules,
-                               positions=positions, mode="train",
-                               enc_out=enc_out)
-        return h
+            h, _, st = apply_block(pparams[f"blk{i}"], h, cfg, kind, i,
+                                   rules, positions=positions, mode="train",
+                                   enc_out=enc_out)
+            if st is not None:
+                stats.append(st)
+        return h, stats
 
     def body(carry, xs):
         hp, hm = carry
@@ -257,21 +293,31 @@ def run_periods_paired(periods, x_pair, cfg: ModelConfig, rules, *,
         # fused into a matmul as an operand, the noise would be generated
         # again for every weight tile
         pp, pm = jax.lax.optimization_barrier((pp, pm))
-        hp = one(hp, pp, enc_pair[0])
-        hm = one(hm, pm, enc_pair[1])
-        return (hp, hm), None
+        hp, sp = one(hp, pp, enc_pair[0])
+        hm, sm = one(hm, pm, enc_pair[1])
+        st = sp + sm
+        if not st:
+            return (hp, hm), None
+        return (hp, hm), (sum(a for a, _ in st),
+                          functools.reduce(jnp.maximum, [b for _, b in st]))
 
     if remat:
         body = jax.checkpoint(body)
     n = jax.tree.leaves(periods)[0].shape[0]
     idx = jnp.arange(n, dtype=jnp.int32)
     if unroll:
+        ys = []
         for i in range(n):
-            x_pair, _ = body(x_pair, (jax.tree.map(lambda a: a[i], periods),
+            x_pair, y = body(x_pair, (jax.tree.map(lambda a: a[i], periods),
                                       jnp.int32(i)))
-        return x_pair
-    x_pair, _ = jax.lax.scan(body, x_pair, (periods, idx))
-    return x_pair
+            ys.append(y)
+        ys = None if ys[0] is None else jax.tree.map(
+            lambda *a: jnp.stack(a), *ys)
+    else:
+        x_pair, ys = jax.lax.scan(body, x_pair, (periods, idx))
+    if ys is None:
+        return x_pair, None
+    return x_pair, (jnp.sum(ys[0]), jnp.max(ys[1]))
 
 
 # --------------------------------------------------------------------- #
@@ -305,8 +351,8 @@ def run_encoder(params, frames, cfg: ModelConfig, rules, unroll=False):
                                   sliding_window=0, rope_theta=0.0)
 
     def body(h, pparams):
-        h, _ = apply_block(pparams["blk0"], h, enc_cfg, ATTN, 0, rules,
-                           positions=pos, mode="encode", causal=False)
+        h, _, _ = apply_block(pparams["blk0"], h, enc_cfg, ATTN, 0, rules,
+                              positions=pos, mode="encode", causal=False)
         return h, None
 
     if unroll:

@@ -29,12 +29,17 @@ def _spec_for(path_names, leaf_name, rules: ShardingRules):
         return r.spec_attn_qkv()
     if n == "wo" and "attn" in path_names or n == "wo" and "cross" in path_names:
         return r.spec_attn_o()
-    if n in ("q_norm", "k_norm"):
+    if n in ("q_norm", "k_norm", "kv_norm"):
         return (None,)
-    # dense mlp
-    if n in ("w_gate", "w_up") and "moe" not in path_names:
+    if n == "wkv_a":                      # latent attention [D, r + rope]
+        return (r.fsdp, None)
+    if n == "wkv_b":                      # [r, H, nope + v]
+        return r.spec_attn_qkv()
+    # dense mlp (and an MoE layer's shared experts)
+    dense = "moe" not in path_names or "shared" in path_names
+    if n in ("w_gate", "w_up") and dense:
         return r.spec_mlp_in()
-    if n == "w_down" and "moe" not in path_names:
+    if n == "w_down" and dense:
         return r.spec_mlp_out()
     # moe
     if n == "router":
@@ -108,7 +113,8 @@ def param_shardings(abstract_params, rules: ShardingRules):
         spec = _spec_for(names, names[-1], rules)
         if spec is None:
             spec = (None,) * leaf.ndim
-        stacked = any(p in ("periods_zo", "periods_bp", "periods") for p in names)
+        stacked = any(p in ("periods_zo", "periods_bp", "periods", "lead")
+                      for p in names)
         if stacked:
             spec = (None,) + tuple(spec)
         if len(spec) != leaf.ndim:

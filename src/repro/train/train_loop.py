@@ -130,6 +130,12 @@ def run(step_fn: Callable, state: TrainState,
             if ntok:
                 rec.counter("train.tokens").inc(ntok)
             rec.gauge("train.loss").set(float(metrics["loss"]))
+            if "moe.held_rows" in metrics:
+                rec.counter("moe.held_rows").inc(
+                    int(metrics["moe.held_rows"]))
+                top = rec.counter("moe.max_expert_rows")  # the largest yet
+                top.inc(max(0, int(metrics["moe.max_expert_rows"])
+                            - top.value))
         if cfg.log_every and (step % cfg.log_every == 0
                               or step == cfg.total_steps - 1):
             if rec.enabled:

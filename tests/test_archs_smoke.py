@@ -57,6 +57,12 @@ def test_arch_prefill_decode(arch):
     lane = LaneConfig()
     ps = ShapeConfig("p", seq_len=64, global_batch=2, kind="prefill")
     ds = ShapeConfig("d", seq_len=64, global_batch=2, kind="decode")
+    if cfg.is_mla:
+        # latent attention is trained, not served: no latent paged cache
+        for s in (ps, ds):
+            with pytest.raises(NotImplementedError, match="latent paged"):
+                api.build(cfg, s, lane, ShardingRules(None, cfg, s))
+        return
     mp = api.build(cfg, ps, lane, ShardingRules(None, cfg, ps))
     md = api.build(cfg, ds, lane, ShardingRules(None, cfg, ds))
     params = mp.init(jax.random.key(0))

@@ -67,14 +67,21 @@ def test_roofline_model_flops_sane():
 def test_cell_matrix_covers_assignment():
     from repro.configs import cell_matrix, ARCHS, SHAPES
     cells = cell_matrix()
-    assert len(cells) == len(ARCHS) * len(SHAPES) == 40
+    assert len(cells) == len(ARCHS) * len(SHAPES)
     run = [c for c in cells if c[2]]
     skip = [c for c in cells if not c[2]]
     # long_500k runs only for the sub-quadratic archs
     assert {(a, s) for a, s, r, _ in cells if s == "long_500k" and r} == {
         ("mixtral-8x7b", "long_500k"), ("rwkv6-1.6b", "long_500k"),
         ("jamba-v0.1-52b", "long_500k")}
-    assert len(run) == 33 and len(skip) == 7
+    n_sub = sum(a.subquadratic for a in ARCHS.values())
+    n_mla = sum(a.is_mla for a in ARCHS.values())       # trained, not served
+    long = sum(s.long_context for s in SHAPES.values())
+    serve = sum(s.kind != "train" and not s.long_context
+                for s in SHAPES.values())
+    assert len(run) == (len(SHAPES) - long) * len(ARCHS) + long * n_sub \
+        - serve * n_mla
+    assert len(skip) == long * (len(ARCHS) - n_sub) + serve * n_mla
 
 
 @pytest.mark.skipif(

@@ -41,18 +41,24 @@ def _tree_equal(a, b):
 
 @jax.jit
 def _in_scan(zo_part, tokens, key):
-    """The in-scan path's perturbed slices (stacked back by the scan) and
-    embedding rows, made as ``paired_loss`` makes them."""
-    periods = zo_part["periods_zo"]
-    salts, sizes = zo.slice_noise_spec(periods, "['periods_zo']")
+    """The in-scan path's perturbed slices of each layer stack (the
+    leading dense layers' and the ZO periods', stacked back by the scan)
+    and embedding rows, made as ``paired_loss`` makes them."""
     seed = prng.seed_from_key(key)
-    n = jax.tree.leaves(periods)[0].shape[0]
+    plus, minus = {}, {}
+    for group in ("lead", "periods_zo"):
+        if group not in zo_part:
+            continue
+        stack = zo_part[group]
+        salts, sizes = zo.slice_noise_spec(stack, f"['{group}']")
+        n = jax.tree.leaves(stack)[0].shape[0]
 
-    def body(c, xs):
-        sl, i = xs
-        return c, zo.perturb_slice_pair(sl, salts, sizes, i, seed, EPS)
+        def body(c, xs, salts=salts, sizes=sizes):
+            sl, i = xs
+            return c, zo.perturb_slice_pair(sl, salts, sizes, i, seed, EPS)
 
-    _, (plus, minus) = jax.lax.scan(body, 0, (periods, jnp.arange(n)))
+        _, (plus[group], minus[group]) = jax.lax.scan(
+            body, 0, (stack, jnp.arange(n)))
     rows = zo.perturb_rows_pair(zo_part, "embed", tokens, key, EPS)
     return plus, minus, rows
 
@@ -62,19 +68,23 @@ def _materialised(zo_part, key):
     return zo.perturb(zo_part, key, EPS), zo.perturb(zo_part, key, -EPS)
 
 
-@pytest.mark.parametrize("arch", ["qwen3-4b", "mixtral-8x7b"])
+@pytest.mark.parametrize("arch", ["qwen3-4b", "mixtral-8x7b",
+                                  "deepseek-v2-lite"])
 def test_in_scan_noise_is_the_materialised_noise(arch):
-    cfg = reduced(get_arch(arch), num_layers=4)
+    lead = get_arch(arch).first_dense_layers
+    cfg = reduced(get_arch(arch), num_layers=4 + lead)
     m, lane = _built(cfg)
     zo_part, _ = elastic.partition(m.init(jax.random.key(0)), lane)
     assert jax.tree.leaves(zo_part["periods_zo"])[0].shape[0] == 3
+    assert ("lead" in zo_part) == bool(lead)
     key = jax.random.fold_in(jax.random.fold_in(jax.random.key(7), 5), 0)
     tokens = jax.random.randint(jax.random.key(1), (2, 16), 0,
                                 cfg.vocab_size, jnp.int32)
     plus, minus, (rows_p, rows_m) = _in_scan(zo_part, tokens, key)
     ref_p, ref_m = _materialised(zo_part, key)
-    _tree_equal(plus, ref_p["periods_zo"])
-    _tree_equal(minus, ref_m["periods_zo"])
+    for group in plus:
+        _tree_equal(plus[group], ref_p[group])
+        _tree_equal(minus[group], ref_m[group])
     _tree_equal(rows_p, ref_p["embed"][tokens])
     _tree_equal(rows_m, ref_m["embed"][tokens])
     # and the probes moved the weights: the pair is not the identity

@@ -9,7 +9,7 @@ import pytest
 from repro.configs import ARCHS, reduced
 from repro.models import ssm
 from repro.models.layers import rope
-from repro.models.moe import capacity, moe_ffn, init_moe
+from repro.models.moe import moe_ffn, init_moe
 from repro.sharding.rules import ShardingRules
 
 
@@ -127,15 +127,14 @@ def test_swa_locality():
 # MoE
 # ------------------------------------------------------------------ #
 def test_moe_matches_dense_dispatch():
-    """Sort-based dispatch == brute-force per-token expert mixing (when no
-    token overflows capacity)."""
+    """Sort-based dropless dispatch == brute-force per-token expert
+    mixing."""
     cfg = reduced(ARCHS["mixtral-8x7b"])
-    cfg = dataclasses.replace(cfg, capacity_factor=8.0)  # no drops
     rules = ShardingRules(None, cfg, None)
     p = init_moe(jax.random.key(0), cfg, jnp.float32)
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.normal(size=(2, 16, cfg.d_model)) * 0.3, jnp.float32)
-    y = moe_ffn(p, x, cfg, rules)
+    y, _ = moe_ffn(p, x, cfg, rules)
 
     # brute force
     logits = jnp.einsum("bsd,de->bse", x, p["router"])
@@ -155,7 +154,3 @@ def test_moe_matches_dense_dispatch():
     np.testing.assert_allclose(y, ref, rtol=2e-3, atol=2e-3)
 
 
-def test_moe_capacity_drops_are_bounded():
-    cfg = reduced(ARCHS["phi3.5-moe-42b-a6.6b"])
-    assert capacity(cfg, 128) >= 128 * cfg.experts_per_token \
-        * cfg.capacity_factor / cfg.num_experts - 1

@@ -181,3 +181,46 @@ def test_elastic_step_keeps_the_noise_out_of_the_matmuls(one_chip,
     in_scan = [f for f, (_, op_name) in noise.items()
                if "zo_perturb" in op_name and "while" in op_name]
     assert len(in_scan) == len(jax.tree.leaves(params["periods_zo"]))
+
+
+def test_mla_moe_step_compiles_with_grouped_matmul_kernels(one_chip,
+                                                           compile_v5e):
+    """DeepSeek-V2-Lite's elastic step at published widths (one chip's
+    16 of 64 experts, the dense layer and two MoE layers): the held
+    experts' grouped matmuls become the compiler's ragged-dot kernels,
+    three for each probe of the ZO MoE layer, and no fusion holds both
+    the noise hash and a matmul; the leading dense layer's noise is made
+    inside its own scan, one fusion a leaf."""
+    cfg = dataclasses.replace(get_arch("deepseek-v2-lite"), num_layers=3,
+                              experts_held=16)
+    B, S = 4, 1024
+    shape = ShapeConfig("t", seq_len=S, global_batch=B, kind="train")
+    lane = LaneConfig(lane="elastic_zo", bp_tail_layers=1)
+    m = api.build(cfg, shape, lane, ShardingRules(None, cfg, shape))
+
+    def spec(shape_, dtype):
+        return jax.ShapeDtypeStruct(shape_, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: spec(a.shape, a.dtype),
+                          m.abstract_params())
+    state = TrainState(params, spec((), jnp.int32), spec((2,), jnp.uint32))
+    batch = {"tokens": spec((B, S), jnp.int32),
+             "labels": spec((B, S), jnp.int32),
+             "mask": spec((B, S), jnp.float32)}
+    compiled = jax.jit(m.train_step, donate_argnums=(0,)).lower(
+        state, batch, spec((1,), jnp.float32)).compile()
+    text = compiled.as_text()
+    gmm = [n for n in (_INSTR.match(line) for line in text.splitlines())
+           if n and n.group(1).startswith("ragged-dot-none")
+           and "tpu_custom_call" in n.group(2)]
+    assert len(gmm) >= 2 * 3 + 2 * 3      # ZO layer's probes; tail's
+    noise = {f: v for f, v in _fusions(text).items()
+             if {"xor", "shift-right-logical"} <= v[0]}
+    assert not [f for f, (ops, _) in noise.items()
+                if ops & {"convolution", "dot"}]
+    in_scan = [f for f, (_, op_name) in noise.items()
+               if "zo_perturb" in op_name and "while" in op_name]
+    assert len(in_scan) == len(jax.tree.leaves(params["periods_zo"])) \
+        + len(jax.tree.leaves(params["lead"]))
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 16e9

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
+from .flash_attn import blocks as flash_blocks
 from .flash_attn import flash_attention as _pallas_flash_attention
 from .int8_matmul import int8_matmul as _pallas_int8_matmul
 from .paged_attn import paged_attention_step as _pallas_paged_attention_step
@@ -29,13 +30,23 @@ def _on_tpu() -> bool:
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale: float | None = None, force_pallas: bool = False,
                     interpret: bool = False):
-    """Online-softmax attention, q/k/v [B,H,S,D] head-major — Pallas on
-    TPU (S must be 128-aligned there), dense-softmax ref elsewhere."""
+    """Online-softmax attention over grouped queries, q [B,Sq,KV,G,Dh],
+    k/v [B,Sk,KV,Dh] — Pallas on TPU (tiles dividing each length; see
+    ``flash_engages``), dense-softmax ref elsewhere."""
     if _on_tpu() or force_pallas:
         return _pallas_flash_attention(q, k, v, causal=causal, window=window,
                                        scale=scale, interpret=interpret)
     return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                    scale=scale)
+
+
+def flash_engages(seq_len: int, head_dim: int) -> bool:
+    """Whether causal self-attention over `seq_len` tokens runs in the
+    Pallas flash kernel: on TPU only (elsewhere the model keeps its own
+    XLA attention, and its numerics), at head widths the TPU's lanes tile,
+    and over at least two key tiles, so that a masked tile is skipped."""
+    return (_on_tpu() and head_dim % 128 == 0
+            and flash_blocks(seq_len) is not None)
 
 
 def int8_matmul(a, w, *, force_pallas: bool = False, interpret: bool = False):

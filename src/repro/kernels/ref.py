@@ -11,12 +11,14 @@ from ..core import prng
 
 
 def flash_attention_ref(q, k, v, *, causal=True, window=0, scale=None):
-    """Dense-softmax oracle for kernels/flash_attn.py, same layout."""
-    B, H, Sq, D = q.shape
-    Sk = k.shape[2]
-    scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale
+    """Dense-softmax oracle for kernels/flash_attn.py, same layout (q
+    [B, Sq, KV, G, Dh], k/v [B, Sk, KV, Dh]) and precision: operands in
+    their own dtype with float32 accumulation, float32 softmax, the
+    probabilities cast to ``v.dtype`` for PV."""
+    Sq, Sk, Dh = q.shape[1], k.shape[1], q.shape[-1]
+    scale = scale if scale is not None else 1.0 / math.sqrt(Dh)
+    s = jnp.einsum("bskgh,btkh->bksgt", q, k,
+                   preferred_element_type=jnp.float32) * scale
     q_pos = jnp.arange(Sq)[:, None]
     k_pos = jnp.arange(Sk)[None, :]
     mask = jnp.ones((Sq, Sk), bool)
@@ -24,10 +26,10 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0, scale=None):
         mask &= k_pos <= q_pos
     if window > 0:
         mask &= k_pos > q_pos - window
-    s = jnp.where(mask[None, None], s, NEG_INF)
+    s = jnp.where(mask[:, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p,
-                      v.astype(jnp.float32)).astype(q.dtype)
+    return jnp.einsum("bksgt,btkh->bskgh", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(v.dtype)
 
 
 def int8_matmul_ref(a: jax.Array, w: jax.Array):

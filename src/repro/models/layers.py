@@ -13,7 +13,9 @@ Attention supports two sharding plans chosen by ``ShardingRules``:
 
 All score computation is query-chunked (block-causal) so that 32k-token
 prefill never materializes an SxS score tensor, and sliding-window archs
-only compute the banded blocks. Chunking is a python-level unrolled loop:
+only compute the banded blocks. The elastic step's ZO forward runs causal
+self-attention in the Pallas flash kernel instead, on TPU
+(``flash_engages``). Chunking is a python-level unrolled loop:
 no ``lax.scan``, so ``cost_analysis`` sees every FLOP (docs/design.md §7).
 """
 from __future__ import annotations
@@ -257,7 +259,8 @@ def attention(p, x, cfg: ModelConfig, rules, positions,
               cache: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
               cache_len=None, write_cache=False,
               kv_override: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
-              paged: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None):
+              paged: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
+              flash: bool = False):
     """Returns (y, new_cache_or_None).
 
     cache: (k_cache, v_cache) each [B, T, KVd, Dh] (already kv-duplicated).
@@ -265,6 +268,8 @@ def attention(p, x, cfg: ModelConfig, rules, positions,
     paged: (page_table [B, P], seq_lens [B]) — decode against a paged KV
       pool; ``cache`` then holds (k_pool, v_pool) [N_pages, ps, KVd, Dh]
       shared by all sequences, and per-row positions come from seq_lens.
+    flash: causal self-attention at positions 0..S-1 may run in the Pallas
+      flash kernel where ``flash_engages`` says it can (the ZO forward).
     """
     B, S, d = x.shape
     H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -344,6 +349,10 @@ def attention(p, x, cfg: ModelConfig, rules, positions,
         # prefill: attend over self (chunked) and return the cache
         y = _chunked_self_attention(q, k, v, positions, causal, window, scale, rules)
         new_cache = (k, v)
+    elif flash and causal and kv_override is None \
+            and flash_engages(cfg, rules, S):
+        from ..kernels import ops
+        y = ops.flash_attention(q, k, v, scale=scale)
     else:
         y = _chunked_self_attention(q, k, v, positions, causal, window, scale, rules)
 
@@ -352,6 +361,18 @@ def attention(p, x, cfg: ModelConfig, rules, positions,
         y = y[:, :, :H, :]
     out = jnp.einsum("bshk,hkd->bsd", y, p["wo"])
     return rules.act_btd(out), new_cache
+
+
+def flash_engages(cfg: ModelConfig, rules, seq_len: int) -> bool:
+    """Whether causal self-attention over `seq_len` tokens may run in the
+    Pallas flash kernel (kernels/ops.py ``flash_engages``): dense GQA
+    attention, on one device (the kernel is not partitioned over a mesh),
+    with no window shorter than the sequence."""
+    from ..kernels import ops
+    window = cfg.sliding_window
+    return (not cfg.is_mla and rules.mesh is None
+            and (window == 0 or window >= seq_len)
+            and ops.flash_engages(seq_len, cfg.head_dim))
 
 
 def _ring_write(cache, x, pos):

@@ -20,9 +20,11 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..configs.base import ATTN, MAMBA, RWKV, ModelConfig
-from .layers import (attention, dense_init, init_attention, init_mla,
-                     init_mlp, mla_attention, mlp, rms_norm, subkey)
+from .layers import (attention, dense_init, flash_engages, init_attention,
+                     init_mla, init_mlp, mla_attention, mlp, rms_norm,
+                     subkey)
 from .moe import init_moe, moe_ffn
 from .ssm import (init_mamba_block, init_mamba_state, init_rwkv_block,
                   init_rwkv_state, mamba_block, rwkv_block)
@@ -115,13 +117,14 @@ def init_lm(key, cfg: ModelConfig, max_seq: int, dtype=None):
 def apply_block(p, x, cfg: ModelConfig, kind: str, pos: int, rules, *,
                 positions, mode: str, cache=None, cache_len=None,
                 enc_out=None, cross_cache=None, causal: bool = True,
-                paged=None, full_kv: bool = False):
+                paged=None, full_kv: bool = False, flash: bool = False):
     """Returns (x, new_cache_entry, moe_stats).
 
     paged: (page_table, seq_lens) — decode against the paged KV pool
     (serve subsystem); full_kv: prefill returns the un-rolled full-length
     KV even for SWA archs (the paged pool stores absolute positions and
-    applies the window as a mask instead of a ring buffer). moe_stats:
+    applies the window as a mask instead of a ring buffer); flash: the
+    self-attention may run in the flash kernel (``attention``). moe_stats:
     (held rows, most rows on one expert) of an MoE FFN, else None.
     """
     if kind == RWKV:
@@ -145,8 +148,8 @@ def apply_block(p, x, cfg: ModelConfig, kind: str, pos: int, rules, *,
             new_cache.update(k=kv[0], v=kv[1])
         else:
             y, kv = attention(p["attn"], h, cfg, rules, positions,
-                              causal=causal,
-                              window=window, write_cache=(mode == "prefill"))
+                              causal=causal, window=window,
+                              write_cache=(mode == "prefill"), flash=flash)
             if mode == "prefill":
                 k, v = kv
                 if window and k.shape[1] > window and not full_kv:
@@ -261,6 +264,12 @@ def run_periods_paired(periods, x_pair, cfg: ModelConfig, rules, *,
     flat-offset property of core/prng.py, so each perturbed slice is
     bitwise that of ``zo.perturb`` on the stacked leaf. Train mode only.
 
+    Nothing is differentiated through this forward (its output is
+    stop-gradiented), so its causal self-attention over positions
+    0..S-1 runs in the Pallas flash kernel where ``layers.flash_engages``
+    allows; the attention layer-passes that do are counted at trace time
+    under ``attn.flash_passes`` (docs/observability.md).
+
     Returns (x_pair, stats): stats is None without MoE FFNs, else the
     rows routed to held experts summed over the layers and both probes
     and the most rows on one held expert in one layer (int32).
@@ -273,7 +282,7 @@ def run_periods_paired(periods, x_pair, cfg: ModelConfig, rules, *,
         for i, kind in enumerate(pattern):
             h, _, st = apply_block(pparams[f"blk{i}"], h, cfg, kind, i,
                                    rules, positions=positions, mode="train",
-                                   enc_out=enc_out)
+                                   enc_out=enc_out, flash=True)
             if st is not None:
                 stats.append(st)
         return h, stats
@@ -304,6 +313,9 @@ def run_periods_paired(periods, x_pair, cfg: ModelConfig, rules, *,
     if remat:
         body = jax.checkpoint(body)
     n = jax.tree.leaves(periods)[0].shape[0]
+    if flash_engages(cfg, rules, x_pair[0].shape[1]):
+        obs.get().counter("attn.flash_passes").inc(
+            2 * n * sum(kind == ATTN for kind in pattern))
     idx = jnp.arange(n, dtype=jnp.int32)
     if unroll:
         ys = []

@@ -11,7 +11,8 @@ The topology is described inside a fixture, never at import: only the
 process that runs these tests loads the TPU compiler library.
 
 The elastic training step is compiled here too, at qwen3-4b widths with
-two layers, to read where the compiler puts the ZO noise.
+two layers, to read where the compiler puts the ZO noise and that the
+ZO forward's attention runs in the flash kernel.
 """
 import dataclasses
 import os
@@ -26,7 +27,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import LaneConfig, ShapeConfig, get_arch
 from repro.core import api
 from repro.core.elastic import TrainState
-from repro.kernels import paged_attn, topk_mask, zo_fused_replay
+from repro.kernels import (flash_attn, ops, paged_attn, topk_mask,
+                           zo_fused_replay)
 from repro.sharding.rules import ShardingRules
 
 QWEN = get_arch("qwen3-4b")
@@ -80,6 +82,16 @@ def test_paged_attention_compiles_at_qwen3_4b_serve_shapes(compile_v5e):
             q, kn, vn, kp, vp, pt, sl, scale=Dh ** -0.5),
         ((B, KVd, G, Dh), bf), ((B, KVd, Dh), bf), ((B, KVd, Dh), bf),
         pool, pool, ((B, PAGES_PER_SEQ), jnp.int32), ((B,), jnp.int32))
+    assert _has_kernel(c)
+
+
+def test_flash_attention_compiles_at_qwen3_4b_zo_forward_shapes(compile_v5e):
+    B, S, KV, Dh = 4, 1024, QWEN.num_kv_heads, QWEN.head_dim
+    G = QWEN.num_heads // KV
+    bf = jnp.bfloat16
+    c = compile_v5e(lambda q, k, v: flash_attn.flash_attention(q, k, v),
+                    ((B, S, KV, G, Dh), bf), ((B, S, KV, Dh), bf),
+                    ((B, S, KV, Dh), bf))
     assert _has_kernel(c)
 
 
@@ -224,3 +236,48 @@ def test_mla_moe_step_compiles_with_grouped_matmul_kernels(one_chip,
         + len(jax.tree.leaves(params["lead"]))
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 16e9
+
+
+def test_elastic_step_keeps_the_zo_score_block_in_the_kernel(
+        one_chip, compile_v5e, monkeypatch):
+    """With the TPU's dispatch (which a described chip does not select by
+    itself), the two-layer qwen3-4b step at 4 x 1024 tokens runs the ZO
+    layer's attention in the flash kernel for both probes: no float32
+    score block ``f32[4,8,1024,4,1024]`` is left in ``zo_forward``, while
+    the BP tail's XLA attention still builds one."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(QWEN, num_layers=2)
+    B, S = 4, 1024
+    shape = ShapeConfig("t", seq_len=S, global_batch=B, kind="train")
+    lane = LaneConfig(lane="elastic_zo", bp_tail_layers=1)
+    m = api.build(cfg, shape, lane, ShardingRules(None, cfg, shape))
+
+    def spec(shape_, dtype):
+        return jax.ShapeDtypeStruct(shape_, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: spec(a.shape, a.dtype),
+                          m.abstract_params())
+    state = TrainState(params, spec((), jnp.int32), spec((2,), jnp.uint32))
+    batch = {"tokens": spec((B, S), jnp.int32),
+             "labels": spec((B, S), jnp.int32),
+             "mask": spec((B, S), jnp.float32)}
+    text = jax.jit(m.train_step, donate_argnums=(0,)).lower(
+        state, batch, spec((1,), jnp.float32)).compile().as_text()
+    scores = {"zo_forward": 0, "bp_tail": 0}
+    kernels = 0
+    for line in text.splitlines():
+        m_ = _INSTR.match(line)
+        if m_ is None:
+            continue
+        op_name = _OP_NAME.search(m_.group(2))
+        op_name = op_name.group(1) if op_name else ""
+        head = m_.group(2).split("metadata=")[0]
+        if head.startswith("f32[4,8,1024,4,1024]"):
+            for phase in scores:
+                scores[phase] += phase in op_name
+        if "tpu_custom_call" in head and "zo_forward" in op_name \
+                and "flash_attention" in op_name:
+            kernels += 1
+    assert kernels == 2
+    assert scores["zo_forward"] == 0
+    assert scores["bp_tail"] > 0
